@@ -104,7 +104,7 @@ def run_cell(params, batch, layout, topology: str, codec: str, t_inner: int,
     m = None
     for _ in range(rounds):
         state, m = rnd(state, batch)
-    wire = int(m["wire_bytes"])
+    wire = rnd.wire_bytes(state)["wire_bytes"]
     # the metric must agree with the exchange's static accounting
     assert wire == ex.wire_bytes_per_round(layout.size), (
         wire, ex.wire_bytes_per_round(layout.size))
@@ -151,10 +151,11 @@ def run_moment_cell(params, batch, layout, opt_name: str,
         state, m = rnd(state, batch)
     moment_sizes = {k: layout.padded for k in opt.moment_keys}
     by_stream = ex.wire_bytes_by_stream(layout.padded, moment_sizes)
-    wire = int(m["wire_bytes"])
+    wb = rnd.wire_bytes(state)
+    wire = wb["wire_bytes"]
     assert wire == sum(by_stream.values()), (wire, by_stream)
     for k, v in by_stream.items():
-        assert int(m[f"wire_bytes/{k}"]) == v, (k, v)
+        assert wb[f"wire_bytes/{k}"] == v, (k, v)
     gsq = float(jnp.mean(m["grad_sq"]))
     return {
         "wire_bytes_per_round": wire,
@@ -204,7 +205,7 @@ def run_fig2(codec: str, rounds: int, tol: float = FIG2_TOL) -> dict:
     gsq, wire = [], 0
     for _ in range(rounds):
         state, m = rnd(state, batch)
-        wire += int(m["wire_bytes"])
+        wire += rnd.wire_bytes(state)["wire_bytes"]
         gsq.append(float(global_gsq(state["params"][0])))
     n = np.arange(1, rounds + 1)
     tail = slice(rounds // 10, None)

@@ -17,12 +17,15 @@ def child_env(force_devices: int = 0) -> dict:
     (venv interpreters, PATH, XLA flags — PR 2 broke comm_reduction by
     rebuilding a bare env), PREPEND repo src to PYTHONPATH, and
     optionally force a host-platform device count (jax locks the count
-    at first init, so multi-device runs need a fresh process)."""
+    at first init, so multi-device runs need a fresh process). A forced
+    count is a CPU device count, so that child is pinned to the CPU:
+    on an accelerator host the parent already holds the chip."""
     env = dict(os.environ)
     root = Path(__file__).resolve().parents[1]
     env["PYTHONPATH"] = str(root / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     if force_devices:
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={force_devices} "
             + env.get("XLA_FLAGS", "")).strip()

@@ -107,7 +107,7 @@ def run_cell(params, batch, layout, topology: str, drop: float,
         state, m = rnd(state, batch)
         if "participation" in m:
             parts.append(float(m["participation"]))
-    wire = int(m["wire_bytes"])
+    wire = rnd.wire_bytes(state)["wire_bytes"]
     # sharded layouts pad the buffer to the shard grid; the round prices
     # the actual (padded) payload it ships
     assert wire == ex.wire_bytes_per_round(layout.padded), (

@@ -281,6 +281,8 @@ def _trace_overhead_row(reps: int, bar: float) -> dict:
     opt = optim.get("sgd", 0.05, packed=True)
     rnd = jax.jit(lsgd.make_local_round(probe_loss, opt, lcfg,
                                         layout=layout), donate_argnums=(0,))
+    wire = rnd.wire_bytes(lsgd.init_state(params, opt, n_groups=G,
+                                          layout=layout))
     tr = bench_trace("trace_overhead",
                      meta={"config": cfg.name, "T": t_inner, "opt": "sgd"})
 
@@ -292,7 +294,7 @@ def _trace_overhead_row(reps: int, bar: float) -> dict:
                 t0 = time.time()
                 with tr.phase("round") as f:
                     self.state, m = f(self.fn(self.state, self.batch))
-                tr.emit_round(_TracedRunner.n, m)
+                tr.emit_round(_TracedRunner.n, {**m, **wire})
                 _TracedRunner.n += 1
                 self.times.append(time.time() - t0)
 

@@ -121,7 +121,7 @@ def run_cell(params, batch, layout, ex, t_inner: int, rounds: int,
     for _ in range(rounds):
         state, m = rnd(state, batch)
     by_tier = ex.wire_bytes_by_tier(layout.padded)
-    wire = int(m["wire_bytes"])
+    wire = rnd.wire_bytes(state)["wire_bytes"]
     assert wire == by_tier["intra"] + by_tier["inter"], (wire, by_tier)
     return {
         "wire_bytes_per_round": wire,

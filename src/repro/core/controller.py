@@ -21,7 +21,7 @@ class AdaptiveT:
       allreduce_time_est from the dry-run HLO terms (launch/roofline.py).
     * measured, codec-aware: ``AdaptiveT.from_comm_bytes`` takes the EXACT
       per-round wire bytes the round's Exchange reports
-      (``metrics["wire_bytes"]`` / ``Exchange.wire_bytes_per_round``) and
+      (``round_.wire_bytes`` / ``Exchange.wire_bytes_per_round``) and
       a link bandwidth — so switching codec (int8 cuts bytes ~4x) changes
       r, and with it the cost-optimal T*.
     """

@@ -158,17 +158,24 @@ def _check_comm_state(exch, state_G, mkeys=()):
             "(DESIGN.md §14)")
 
 
-def _round_wire_bytes(exch, params_G, opt_G, avg_opt: bool,
-                      n_groups: int) -> dict:
-    """Exact payload bytes this round puts on the wire (static ints —
-    shapes only), matching what the round actually exchanges: every
+def round_wire_bytes(exch, params_G, opt_G, avg_opt: bool,
+                     n_groups: int) -> dict:
+    """Exact payload bytes one round puts on the wire (Python ints from
+    shapes only — arrays or ``ShapeDtypeStruct``s), matching what the
+    round actually exchanges: every
     stream of the payload through ITS codec — params via the params
     codec, each moment stream via the moment codec (DESIGN.md §10). The
     step counter is never exchanged on either path. Returns the totals
     (``wire_bytes`` — the physical total, p2p payloads count once —
     plus per-direction ``wire_bytes_up`` / ``wire_bytes_down``) and one
     ``wire_bytes/<stream>`` key per stream; the totals are exactly the
-    sums of the per-stream splits."""
+    sums of the per-stream splits.
+
+    The counts stay on the host: a jitted output would carry them as
+    int32, which wraps above 2 GiB of wire per round (a full-width model
+    at G=4 is past that). Each round function exposes them as
+    ``round_.wire_bytes(state_G)``; the caller merges them into the round
+    record (DESIGN.md §13)."""
     n = sum(l.size // n_groups for l in jax.tree.leaves(params_G))
     moment_sizes = {}
     if avg_opt:
@@ -187,6 +194,16 @@ def _round_wire_bytes(exch, params_G, opt_G, avg_opt: bool,
            "wire_bytes_inter": by_tier["inter"]}
     out.update({f"wire_bytes/{k}": v for k, v in by_stream.items()})
     return out
+
+
+def _with_wire_bytes(round_, exch, cfg: LocalSGDConfig):
+    """Attach ``round_.wire_bytes(state_G)``: the host-side exact wire
+    counts of one round on that state (see ``round_wire_bytes``).
+    ``jax.jit`` copies the attribute onto the jitted round."""
+    round_.wire_bytes = lambda state_G: round_wire_bytes(
+        exch, state_G["params"], state_G["opt"], cfg.average_opt_state,
+        cfg.n_groups)
+    return round_
 
 
 def _clamp_nonneg_streams(mixed: dict, opt, exch) -> dict:
@@ -336,7 +353,8 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
 
     ``exchange`` selects the communication backend (repro.comm,
     DESIGN.md §8): topology x codec + exact wire-byte accounting
-    (``metrics["wire_bytes"]`` + per-direction up/down). Default:
+    (``round_.wire_bytes(state_G)`` — host-side ints, merged into the
+    round record by the caller). Default:
     server/fp32 — bit-exact with the pre-comm ``average_groups``.
 
     ``shardexec`` (a ``sharding.shardexec.ShardExec``, packed path only)
@@ -350,8 +368,9 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
             raise ValueError(
                 "packed rounds need BOTH a packing.Layout and a packed "
                 "optimizer (optim.packed / optim.get(..., packed=True))")
-        return _make_packed_local_round(loss_fn, opt, cfg, layout, exch,
-                                        shardexec)
+        return _with_wire_bytes(
+            _make_packed_local_round(loss_fn, opt, cfg, layout, exch,
+                                     shardexec), exch, cfg)
     if shardexec is not None:
         raise ValueError(
             "shardexec shards the packed flat buffer — it has no meaning "
@@ -454,9 +473,6 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
             mixed, comm_state = exch.streams(xs, xs0, comm_state)
         mixed = _clamp_nonneg_streams(mixed, opt, exch)
         new_opt = {k: mixed.get(k, v) for k, v in st["opt"].items()}
-        metrics.update(_round_wire_bytes(
-            exch, st["params"], st["opt"], cfg.average_opt_state,
-            cfg.n_groups))
         with jax.named_scope("round_metrics"):
             metrics.update(_obs_round_metrics(
                 exch, comm_state, ("params",) + mkeys,
@@ -467,7 +483,7 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
             out["comm"] = comm_state
         return out, metrics
 
-    return round_
+    return _with_wire_bytes(round_, exch, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -648,12 +664,13 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             vg = jax.value_and_grad(loss_fn)
 
             def final_eval(buf, b):
-                loss, g_tree = vg(packing.unpack(buf, layout), b)
+                loss, g_tree = vg(packing.unpack_for_compute(buf, layout),
+                                  b)
                 return loss, grad_sq_norm(g_tree)
 
             with jax.named_scope("final_eval"):
-                loss_G, gsq_G = jax.vmap(final_eval)(state_G["params"],
-                                                     last_batch)
+                loss_G, gsq_G = jax.vmap(final_eval)(
+                    state_G["params"], last_batch)
             metrics = {"loss": loss_G,
                        "inner_steps": n_steps,
                        "grad_sq": gsq_G}
@@ -692,9 +709,6 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
                 mixed, comm_state = exch_streams(xs, xs0, comm_state)
             mixed = _clamp_nonneg_streams(mixed, opt, exch)
         new_opt = {k: mixed.get(k, v) for k, v in state_G["opt"].items()}
-        metrics.update(_round_wire_bytes(
-            exch, state_G["params"], state_G["opt"],
-            cfg.average_opt_state, cfg.n_groups))
         with jax.named_scope("round_metrics"):
             metrics.update(_obs_round_metrics(
                 exch, comm_state, ("params",) + tuple(mkeys),

@@ -2,7 +2,6 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 import jax as _jax
-import jax.numpy as _jnp
 
 
 def use_interpret() -> bool:
@@ -39,14 +38,23 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
-def pad_to_block(block: int, *xs):
-    """Shared 1-D blocking prep for the flat-buffer kernels: clamp the
-    block to n, zero-pad every array to a block multiple.
+def as_rows(x):
+    """A packed buffer as the 2-D (rows, N) view the flat-buffer kernels
+    take: a 1-D buffer is one row, the (G, N) grouped one is itself."""
+    return x.reshape(-1, x.shape[-1])
 
-    Returns (block, grid, padded_arrays, n) — slice outputs back to n."""
-    n = xs[0].shape[0]
-    block = min(block, n)
-    pad = (-n) % block
-    if pad:
-        xs = tuple(_jnp.pad(x, (0, pad)) for x in xs)
-    return block, (xs[0].shape[0] // block,), xs, n
+
+def flat_blocks(shape, block: int):
+    """Shared blocking for the element-wise flat-buffer kernels over a
+    (rows, N) buffer: blocks of every row by ``cols`` columns — a
+    multiple of 128 holding about ``block`` elements, or all of N — and a
+    grid covering N. Whole rows and 128-multiple columns satisfy the
+    TPU's (8, 128) block rule for any row count. A partial last block
+    needs no padding: an element-wise update never mixes the don't-care
+    tail into real elements and writes past N are dropped, so no operand
+    is ever copied.
+
+    Returns (block_shape, grid)."""
+    rows, n = shape
+    cols = min(max(128, block // rows // 128 * 128), n)
+    return (rows, cols), (-(-n // cols),)

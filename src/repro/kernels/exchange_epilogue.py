@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.quantize import row_block
+
 KINDS = ("int8", "bf16", "fp16", "thresh")
 
 # column block of the codec_mix grid: a multiple of every codec chunk in
@@ -217,7 +219,7 @@ def codec_mix(x, x0, *, kind: str, u=None, w=None, hops: int = 1,
 
 def _qdq_kernel(x_ref, u_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x))
+    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0.0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.floor(x / scale + u_ref[...].astype(jnp.float32)),
                  -127.0, 127.0).astype(jnp.int8)
@@ -227,14 +229,16 @@ def _qdq_kernel(x_ref, u_ref, o_ref):
 def qdq_int8(x, u, *, interpret: bool = True):
     """(rows, chunk) f32 + uniform noise -> decoded (rows, chunk) f32 in
     ONE VMEM pass (the staged pair kernels/quantize.py quantize_int8 +
-    dequantize_int8 re-reads every row; same math, bit-identical)."""
+    dequantize_int8 re-reads every row; same math, same row blocks,
+    bit-identical)."""
     rows, chunk = x.shape
+    rb = row_block(rows)
     return pl.pallas_call(
         _qdq_kernel,
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, chunk), lambda i: (i, 0)),
-                  pl.BlockSpec((1, chunk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, chunk), lambda i: (i, 0)),
+        grid=(pl.cdiv(rows, rb),),
+        in_specs=[pl.BlockSpec((rb, chunk), lambda i: (i, 0)),
+                  pl.BlockSpec((rb, chunk), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rb, chunk), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, chunk), jnp.float32),
         interpret=interpret,
     )(x, u)

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import pad_to_block
+from repro.kernels import as_rows, flat_blocks
 
 
 def _kernel(p_ref, g_ref, m_ref, v_ref, bc_ref, po_ref, mo_ref, vo_ref,
@@ -33,34 +33,24 @@ def _kernel(p_ref, g_ref, m_ref, v_ref, bc_ref, po_ref, mo_ref, vo_ref,
 
 def fused_adamw(p, g, m, v, *, count, lr, b1=0.9, b2=0.999, eps=1e-8,
                 wd=0.0, block: int = 65536, interpret: bool = True):
-    """Flat 1-D arrays p,g,m,v; count = post-increment step number.
-    Returns (new_p, new_m, new_v)."""
+    """Packed buffers p, g, m, v of shape (N,) or (G, N); count = post-
+    increment step number. Returns (new_p, new_m, new_v)."""
     c = jnp.asarray(count, jnp.float32)
     bc = jnp.stack([1.0 - b1 ** c, 1.0 - b2 ** c])
-    block, grid, (pp, gg, mm, vv), n = pad_to_block(block, p, g, m, v)
-
-    new_p, new_m, new_v = pl.pallas_call(
+    p2 = as_rows(p)
+    bs, grid = flat_blocks(p2.shape, block)
+    spec = pl.BlockSpec(bs, lambda i: (0, i))
+    outs = pl.pallas_call(
         functools.partial(_kernel, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
+        in_specs=[spec, spec, spec, spec,
+                  pl.BlockSpec((2,), lambda i: (0,))],
+        out_specs=[spec, spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct(pp.shape, p.dtype),
-            jax.ShapeDtypeStruct(pp.shape, jnp.float32),
-            jax.ShapeDtypeStruct(pp.shape, jnp.float32),
+            jax.ShapeDtypeStruct(p2.shape, p.dtype),
+            jax.ShapeDtypeStruct(p2.shape, jnp.float32),
+            jax.ShapeDtypeStruct(p2.shape, jnp.float32),
         ],
         interpret=interpret,
-    )(pp, gg, mm, vv, bc)
-    if new_p.shape[0] != n:
-        new_p, new_m, new_v = new_p[:n], new_m[:n], new_v[:n]
-    return new_p, new_m, new_v
+    )(p2, as_rows(g), as_rows(m), as_rows(v), bc)
+    return tuple(o.reshape(p.shape) for o in outs)

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import pad_to_block
+from repro.kernels import as_rows, flat_blocks
 
 
 def _kernel(p_ref, g_ref, mu_ref, po_ref, muo_ref, *, lr, beta):
@@ -26,27 +26,20 @@ def _kernel(p_ref, g_ref, mu_ref, po_ref, muo_ref, *, lr, beta):
 
 def fused_momentum(p, g, mu, *, lr, beta=0.9, block: int = 65536,
                    interpret: bool = True):
-    """Flat 1-D arrays p, g, mu. Returns (new_p, new_mu)."""
-    block, grid, (pp, gg, mm), n = pad_to_block(block, p, g, mu)
-
+    """Packed buffers p, g, mu of shape (N,) or (G, N). Returns (new_p,
+    new_mu)."""
+    p2 = as_rows(p)
+    bs, grid = flat_blocks(p2.shape, block)
+    spec = pl.BlockSpec(bs, lambda i: (0, i))
     new_p, new_mu = pl.pallas_call(
         functools.partial(_kernel, lr=lr, beta=beta),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
+        in_specs=[spec, spec, spec],
+        out_specs=[spec, spec],
         out_shape=[
-            jax.ShapeDtypeStruct(pp.shape, p.dtype),
-            jax.ShapeDtypeStruct(pp.shape, jnp.float32),
+            jax.ShapeDtypeStruct(p2.shape, p.dtype),
+            jax.ShapeDtypeStruct(p2.shape, jnp.float32),
         ],
         interpret=interpret,
-    )(pp, gg, mm)
-    if new_p.shape[0] != n:
-        new_p, new_mu = new_p[:n], new_mu[:n]
-    return new_p, new_mu
+    )(p2, as_rows(g), as_rows(mu))
+    return new_p.reshape(p.shape), new_mu.reshape(p.shape)

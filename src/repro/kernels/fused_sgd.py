@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import pad_to_block
+from repro.kernels import as_rows, flat_blocks
 
 
 def _kernel(p_ref, g_ref, po_ref, *, lr):
@@ -22,18 +22,16 @@ def _kernel(p_ref, g_ref, po_ref, *, lr):
 
 
 def fused_sgd(p, g, *, lr, block: int = 65536, interpret: bool = True):
-    """Flat 1-D arrays p, g. Returns new_p."""
-    block, grid, (pp, gg), n = pad_to_block(block, p, g)
-
+    """Packed buffers p, g of shape (N,) or (G, N). Returns new_p."""
+    p2 = as_rows(p)
+    bs, grid = flat_blocks(p2.shape, block)
+    spec = pl.BlockSpec(bs, lambda i: (0, i))
     new_p = pl.pallas_call(
         functools.partial(_kernel, lr=lr),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct(pp.shape, p.dtype),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(p2.shape, p.dtype),
         interpret=interpret,
-    )(pp, gg)
-    return new_p[:n] if new_p.shape[0] != n else new_p
+    )(p2, as_rows(g))
+    return new_p.reshape(p.shape)

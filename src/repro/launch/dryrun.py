@@ -1,12 +1,17 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " "
+                           "--xla_force_host_platform_device_count=512"
+                           ).strip()
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) combination with abstract inputs (no allocation), record
 memory/cost/collective analysis for EXPERIMENTS.md.
 
-The two lines above MUST stay first: jax locks the device count on first
-init, and the production meshes need 512 host platform devices. Smoke
+The lines above MUST stay first: jax locks the platform and the device
+count on first init, and the production meshes need 512 host platform
+devices (CPU ones — on an accelerator host the dry run stays off the
+chip). Smoke
 tests and benchmarks never import this module and keep seeing 1 device.
 
 Usage:

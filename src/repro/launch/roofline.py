@@ -35,8 +35,8 @@ def comm_round_seconds(wire_bytes: float, bandwidth: float = ICI_BW) -> float:
     """Seconds one exchange round's payload spends on the slow link.
 
     ``wire_bytes`` is the EXACT codec-aware payload the comm subsystem
-    reports (``Exchange.wire_bytes_per_round`` / round
-    ``metrics["wire_bytes"]``). Feeds ``AdaptiveT.from_comm_bytes`` — the
+    reports (``Exchange.wire_bytes_per_round`` / the round record's
+    ``wire_bytes``). Feeds ``AdaptiveT.from_comm_bytes`` — the
     measured replacement for the HLO all-reduce estimate this module
     otherwise derives r from."""
     return wire_bytes / bandwidth

@@ -10,8 +10,13 @@ Restore trained weights from a ``launch/train.py --checkpoint`` file
 All timings are phase-fenced (obs.Trace): prefill / decode_step phases
 block_until_ready before reading the clock, and ``--trace`` writes the
 per-step JSONL that ``python -m repro.obs.report <file> --check``
-validates. ``--check-parity`` replays every request through an isolated
-single-slot engine and asserts identical tokens (the CI serve smoke).
+validates. ``--check-parity`` replays every request alone through an
+engine of the same geometry (the same compiled programs, every other
+slot idle) and asserts identical tokens (the CI serve smoke). The same
+slot count matters on a TPU: a batch of 1 compiles to a different
+program than a batch of 4, which rounds differently (on a v5e, by about
+one bf16 step of a logit) and can flip a near-tied greedy argmax without
+any leak between slots.
 """
 from __future__ import annotations
 
@@ -21,6 +26,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.obs.trace import Trace
 from repro.serve import (Engine, EngineConfig, Request, drive_workload,
@@ -35,7 +41,11 @@ def build_engine(model, params, args, policy: str,
         impl=args.impl, policy=policy), trace=trace)
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """Serve a workload for ``argv`` (default: the command line). Returns
+    the run's record: requests done, tokens committed, and whether the
+    isolated-replay parity check ran and held (``parity_ok``: None when
+    not asked for; a failure raises SystemExit)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-32b")
     ap.add_argument("--reduced", action="store_true")
@@ -59,10 +69,11 @@ def main() -> None:
                          "(pytree or packed)")
     ap.add_argument("--trace", default="", help="JSONL trace sink")
     ap.add_argument("--check-parity", action="store_true",
-                    help="replay each request isolated; assert identical "
-                         "tokens")
+                    help="replay each request alone through an engine of "
+                         "the same geometry; assert identical tokens")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -101,11 +112,11 @@ def main() -> None:
           f"p99 {np.percentile(lat, 99):.3f}s")
     if args.trace:
         print(f"trace -> {args.trace} ({trace.n_records} records)")
+    record = {"requests": len(done), "committed": committed,
+              "parity_ok": None}
 
     if args.check_parity:
-        iso = Engine(model, params, EngineConfig(
-            n_slots=1, page_size=args.page_size, max_prompt=args.prompt_max,
-            max_new=args.gen_max, impl=args.impl))
+        iso = build_engine(model, params, args, args.engine)
         got = {c.rid: c.tokens for c in done}
         bad = 0
         for r in reqs:
@@ -118,6 +129,8 @@ def main() -> None:
             raise SystemExit(f"parity check failed for {bad} request(s)")
         print(f"parity OK: {len(reqs)} requests identical to isolated "
               "decode")
+        record["parity_ok"] = True
+    return record
 
 
 if __name__ == "__main__":

@@ -299,7 +299,7 @@ def build_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
                    for s in jax.tree.leaves(tree))
 
     # stream-resolved wire accounting mirrors the round's
-    # _round_wire_bytes: each moment stream rides its own codec; the step
+    # round_wire_bytes: each moment stream rides its own codec; the step
     # counter is never exchanged
     moment_sizes = ({k: _n(v) for k, v in opt_1.items() if k != "count"}
                     if avg_opt else {})
@@ -560,7 +560,7 @@ def _build_packed_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
          "streams": list(slayout.streams),
          # packed rounds exchange every moment stream through its own
          # codec but never the shared step counter (mirrors
-         # _round_wire_bytes); totals == sums of the per-stream splits
+         # round_wire_bytes); totals == sums of the per-stream splits
          "wire_bytes_per_round": exchange.wire_bytes_per_round(
              n_wire, moment_sizes=moment_sizes),
          "wire_bytes_up_per_round": exchange.wire_bytes_up(

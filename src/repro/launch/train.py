@@ -4,16 +4,20 @@ baseline) on any assigned architecture.
 On this CPU container run reduced configs:
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b --reduced \
       --rounds 10 --t-inner 4
-On a TPU pod the same entry point runs the full config on the production
-mesh (--mesh pod).
+On an accelerator host the same entry point runs the full config; the
+G groups spread over the first G*S devices whenever the process sees
+that many (one group per chip on a four-chip host at --shard 1).
+``main(argv)`` also returns the run's record (see its docstring).
 """
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import comm as comm_mod
 from repro import obs
@@ -23,6 +27,7 @@ from repro.configs.base import get_config
 from repro.core import localsgd as lsgd
 from repro.core.controller import AdaptiveT, OnlineT
 from repro.data.synthetic import TokenPipeline
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.optim import packing
 
@@ -75,7 +80,18 @@ def calibrate_fences(loss_fn, opt, lcfg, layout, exchange, sexec, params,
     return local_ref_s / max(lcfg.inner_steps, 1), exch_ref_s
 
 
-def main() -> None:
+def custom_calls(hlo: str) -> int:
+    """Number of compiled Pallas TPU kernels in an HLO text."""
+    return hlo.count('custom_call_target="tpu_custom_call"')
+
+
+def main(argv=None, devices=None) -> dict:
+    """Run the trainer on ``argv`` (default: the command line).
+
+    ``devices`` (default ``jax.devices()``) is the device list groups are
+    placed on. Returns the run's record: ``history`` (per-round mean loss
+    and grad_sq), ``compile_s`` and ``hlo`` of the first compiled round
+    (local-SGD mode), the final ``state`` and the server ``params``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="paper-lenet")
     ap.add_argument("--reduced", action="store_true")
@@ -204,7 +220,8 @@ def main() -> None:
     ap.add_argument("--profile", default="",
                     help="dump a perfetto trace of the run under this "
                          "directory (jax.profiler.start_trace)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.mode == "sync" and (args.comm != "server"
                                 or args.codec != "fp32"
                                 or args.moment_codec != "fp32"
@@ -247,28 +264,33 @@ def main() -> None:
     layout = packing.layout_of(params) if args.packed else None
     G = args.groups
     mesh, sexec = None, None
-    if args.shard > 1:
+    n_dev = G * args.shard
+    devices = list(devices if devices is not None else jax.devices())
+    if args.shard > 1 and len(devices) < n_dev:
+        raise SystemExit(
+            f"--shard {args.shard} with --groups {G} needs {n_dev} "
+            f"devices, found {len(devices)}; set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_dev}")
+    if (args.packed and args.mode == "localsgd" and n_dev > 1
+            and len(devices) >= n_dev):
+        # groups (and in-group shards) over the first G*S devices: the
+        # paper's deployment, one group per node
         from jax.sharding import Mesh
         from repro.sharding import shardexec as shx
 
-        n_dev = G * args.shard
-        devices = jax.devices()
-        if len(devices) < n_dev:
-            raise SystemExit(
-                f"--shard {args.shard} with --groups {G} needs {n_dev} "
-                f"devices, found {len(devices)}; set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n_dev}")
         mesh = Mesh(np.array(devices[:n_dev]).reshape(G, args.shard),
                     ("data", "model"))
         sexec = shx.plan_for(mesh, require=True, hop_impl=args.hop_impl)
         layout = packing.shard_layout(layout, sexec.n_shards)
-        print(f"sharded execution: G={G} x {args.shard} shards, "
-              f"buffer {layout.size} -> {layout.padded} padded "
-              f"({layout.shard_size}/shard)")
+        print(f"sharded execution: G={G} x {args.shard} shards on "
+              f"{n_dev} devices, buffer {layout.size} -> {layout.padded} "
+              f"padded ({layout.shard_size}/shard)")
     opt = optim.get(args.opt, args.lr, packed=args.packed,
                     **({"impl": args.impl} if args.packed else {}))
     pipe = TokenPipeline(cfg.vocab_size, args.seq, seed=args.seed)
     rng = np.random.RandomState(args.seed)
+    history = []
+    compile_s, hlo = None, ""
 
     if args.mode == "sync":
         step = jax.jit(lsgd.make_sync_step(model.loss, opt, layout=layout),
@@ -284,9 +306,11 @@ def main() -> None:
                 with trace.phase("step") as f:
                     state, m = f(step(state, batch))
                 rec = trace.emit_round(n, m, kind="step")
+                history.append({"loss": float(m["loss"]),
+                                "grad_sq": float(m["grad_sq"])})
                 if n % args.log_every == 0:
-                    print(f"step {n:4d} loss {float(m['loss']):.4f} "
-                          f"gsq {float(m['grad_sq']):.3e} "
+                    print(f"step {n:4d} loss {history[-1]['loss']:.4f} "
+                          f"gsq {history[-1]['grad_sq']:.3e} "
                           f"({rec['phase_s'].get('step', 0.0):.2f}s)")
         final = (packing.unpack(state["params"], layout)
                  if args.packed else state["params"])
@@ -331,8 +355,6 @@ def main() -> None:
         if sexec is not None:
             # place the buffers on the mesh once; donation keeps every
             # subsequent round's state resident in place
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
             buf_sh = NamedSharding(mesh, sexec.buf_spec())
             rep_sh = NamedSharding(mesh, P())
             state = jax.tree.map(
@@ -341,6 +363,12 @@ def main() -> None:
                                   and x.shape[-1] == layout.padded)
                     else rep_sh), state)
         batches = pipe.batches((G, args.per_group))
+        batch_sh = None
+        if sexec is not None:
+            batch_sh = NamedSharding(mesh, sexec.group_spec())
+        # exact wire counts come from shapes, on the host (an int32 jit
+        # output would wrap at full width)
+        wire = rnd.wire_bytes(state)
         # on a lossy network each useful round costs a full attempt's
         # worth of link time (AdaptiveT.from_exchange's delivery_rate
         # repricing): comm is 1/delivery more expensive, so r shrinks
@@ -368,6 +396,8 @@ def main() -> None:
                     batch = add_modalities(
                         {"tokens": jnp.asarray(next(batches)["tokens"])},
                         cfg, rng)
+                    if batch_sh is not None:
+                        batch = jax.device_put(batch, batch_sh)
                 if calibrate and n == 0:
                     local_ref_step, exch_ref_s = calibrate_fences(
                         model.loss, opt, lcfg, layout, exchange, sexec,
@@ -380,8 +410,22 @@ def main() -> None:
                         model.loss, opt, lcfg, layout=layout,
                         exchange=exchange, shardexec=sexec),
                         donate_argnums=(0,))
+                if compile_s is None:
+                    # compile the first round ahead of time to time it and
+                    # keep its HLO; the jitted call below reuses that
+                    # executable, and retraces whenever the state's shapes
+                    # change (e.g. the t_i count promotion of round 0)
+                    t0 = time.perf_counter()
+                    lowered = rnd.lower(state, batch)
+                    t1 = time.perf_counter()
+                    hlo = lowered.compile().as_text()
+                    compile_s = time.perf_counter() - t1
+                    print(f"round traced in {t1 - t0:.2f}s, compiled in "
+                          f"{compile_s:.2f}s ({custom_calls(hlo)} Pallas "
+                          "TPU kernel calls)")
                 with trace.phase("round") as f:
                     state, m = f(rnd(state, batch))
+                m = {**m, **wire}
                 t_used = int(jnp.max(m["inner_steps"]))
                 fences = None
                 if calibrate:
@@ -410,13 +454,15 @@ def main() -> None:
                     else:
                         t_cur = ctl.update(traj)
                 rec = trace.emit_round(n, m)
-                wire_total += int(m["wire_bytes"])
+                wire_total += wire["wire_bytes"]
+                history.append({"loss": float(jnp.mean(m["loss"])),
+                                "grad_sq": float(jnp.mean(m["grad_sq"]))})
                 if n % args.log_every == 0:
                     print(f"round {n:4d} "
-                          f"loss {float(jnp.mean(m['loss'])):.4f} "
-                          f"gsq {float(jnp.mean(m['grad_sq'])):.3e} "
-                          f"T {int(jnp.max(m['inner_steps']))} "
-                          f"wire {int(m['wire_bytes']):,}B "
+                          f"loss {history[-1]['loss']:.4f} "
+                          f"gsq {history[-1]['grad_sq']:.3e} "
+                          f"T {t_used} "
+                          f"wire {wire['wire_bytes']:,}B "
                           f"part {float(m['participation']):.2f} "
                           f"cons {float(jnp.mean(m['consensus_sq'])):.3e} "
                           f"({rec['phase_s'].get('round', 0.0):.2f}s)")
@@ -435,6 +481,8 @@ def main() -> None:
     trace.close()
     if args.trace:
         print(f"trace -> {args.trace} ({trace.n_records} records)")
+    return {"history": history, "compile_s": compile_s, "hlo": hlo,
+            "state": state, "params": final}
 
 
 if __name__ == "__main__":

@@ -6,7 +6,9 @@ Three layers, one schema:
   block every round (consensus distance, per-stream codec error mass,
   push-sum backlog mass, participation/delivery) regardless of
   topology/codec/fault configuration, so downstream consumers never
-  branch on which keys exist;
+  branch on which keys exist; the exact wire-byte counts come from
+  shapes on the host (``round_.wire_bytes(state_G)``) and the caller
+  merges them into the same record;
 * host-side phase tracing — ``Trace``/``Trace.phase`` fences with
   ``jax.block_until_ready`` before reading the clock (async dispatch
   makes unfenced deltas lies), annotates phases for the profiler, and

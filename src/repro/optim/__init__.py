@@ -113,8 +113,9 @@ def adamw(lr: float, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
 # optim.packing for the layout contract): the whole per-step update runs as
 # one fused Pallas kernel (TPU) or one XLA fusion (CPU fallback), instead
 # of ~10 element-wise HLO ops per pytree leaf. Buffers may carry leading
-# axes (the local-SGD G axis); the update is element-wise so they are
-# raveled through the kernels and reshaped back.
+# axes (the local-SGD G axis); the kernels block the (G, N) buffer as it
+# is (the raveled form compiled to 3.2 GiB more temporaries for a TPU at
+# paper-lenet's width).
 
 
 def _resolve_impl(impl: str) -> str:
@@ -131,15 +132,6 @@ def map_moments(f, opt_state):
     return {k: (v if k == "count" else f(v)) for k, v in opt_state.items()}
 
 
-def _raveled(fn, *bufs):
-    """Run a flat-kernel fn over arbitrarily-leading-axed buffers."""
-    shape = bufs[0].shape
-    out = fn(*(b.reshape(-1) for b in bufs))
-    if isinstance(out, tuple):
-        return tuple(o.reshape(shape) for o in out)
-    return out.reshape(shape)
-
-
 def packed_sgd(lr: float, *, impl: str = "auto") -> Optimizer:
     impl = _resolve_impl(impl)
 
@@ -150,10 +142,7 @@ def packed_sgd(lr: float, *, impl: str = "auto") -> Optimizer:
         if impl == "pallas":
             from repro.kernels import use_interpret
             from repro.kernels.fused_sgd import fused_sgd
-            new = _raveled(
-                lambda p, g: fused_sgd(p, g, lr=lr,
-                                       interpret=use_interpret()),
-                buf, grads)
+            new = fused_sgd(buf, grads, lr=lr, interpret=use_interpret())
         else:
             new = buf - lr * grads
         return new, {"count": state["count"] + 1}
@@ -173,10 +162,8 @@ def packed_momentum(lr: float, beta: float = 0.9, *,
         if impl == "pallas":
             from repro.kernels import use_interpret
             from repro.kernels.fused_momentum import fused_momentum
-            new, mu = _raveled(
-                lambda p, g, m: fused_momentum(
-                    p, g, m, lr=lr, beta=beta, interpret=use_interpret()),
-                buf, grads, state["mu"])
+            new, mu = fused_momentum(buf, grads, state["mu"], lr=lr,
+                                     beta=beta, interpret=use_interpret())
         else:
             mu = beta * state["mu"] + grads
             new = buf - lr * mu
@@ -201,11 +188,9 @@ def packed_adamw(lr: float, b1: float = 0.9, b2: float = 0.999,
         if impl == "pallas":
             from repro.kernels import use_interpret
             from repro.kernels.fused_adamw import fused_adamw
-            new, m, v = _raveled(
-                lambda p, g, m, v: fused_adamw(
-                    p, g, m, v, count=c, lr=lr, b1=b1, b2=b2, eps=eps,
-                    wd=weight_decay, interpret=use_interpret()),
-                buf, grads, state["m"], state["v"])
+            new, m, v = fused_adamw(
+                buf, grads, state["m"], state["v"], count=c, lr=lr, b1=b1,
+                b2=b2, eps=eps, wd=weight_decay, interpret=use_interpret())
         else:
             # Same math as the per-leaf adamw (bias correction unfolded)
             # so the packed path is bit-compatible up to fma reassociation.

@@ -214,6 +214,15 @@ def unpack(buf: jax.Array, layout: Layout):
     return jax.tree.unflatten(layout.treedef, leaves)
 
 
+def unpack_for_compute(buf: jax.Array, layout: Layout):
+    """``unpack`` for a model's forward/backward: the leaves are
+    materialized once (an optimization barrier) instead of each static
+    slice + reshape being fused into its consumers. Fused into the
+    matmuls of a vmapped (G, N) buffer, those views made the TPU compiler
+    take minutes on a full-width round."""
+    return jax.lax.optimization_barrier(unpack(buf, layout))
+
+
 def chunk_rows(x: jax.Array, chunk: int) -> jax.Array:
     """(..., N) buffer -> (rows, chunk) 2-D view for per-chunk codecs.
 
@@ -258,7 +267,7 @@ def value_and_flat_grad(loss_fn, layout: Layout):
     vg = jax.value_and_grad(loss_fn)
 
     def flat_vg(buf, batch):
-        loss, g_tree = vg(unpack(buf, layout), batch)
+        loss, g_tree = vg(unpack_for_compute(buf, layout), batch)
         return loss, pack(g_tree, layout)
 
     return flat_vg

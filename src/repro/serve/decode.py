@@ -225,7 +225,6 @@ def _build_decoder_programs(model, geom, attn_fn):
     ps = geom.page_size
 
     def step(params, pool, tokens, pos, rows_k, rows_v, srows, active):
-        B = tokens.shape[0]
         x = mapi._embed_lookup(params["embed"], tokens[:, None], dtype,
                                cfg.embed_impl)
         positions = pos[:, None]
@@ -237,10 +236,8 @@ def _build_decoder_programs(model, geom, attn_fn):
             h = rms_norm(x, p["norm1"], eps)
             q, k, v = attn.project_qkv(p["attn"], h, h, cfg, positions,
                                        positions, True)
-            pool = write_token_kv(pool, rk, blk, off,
-                                  k[:, 0].reshape(B, -1), active)
-            pool = write_token_kv(pool, rv, blk, off,
-                                  v[:, 0].reshape(B, -1), active)
+            pool = write_token_kv(pool, rk, blk, off, k[:, 0], active)
+            pool = write_token_kv(pool, rv, blk, off, v[:, 0], active)
             a = attn_fn(q[:, 0], pool, rk, rv, lengths)
             x = x + attn.output_proj(p["attn"], a[:, None].astype(x.dtype))
             h2 = rms_norm(x, p["norm2"], eps)
@@ -279,9 +276,9 @@ def _build_decoder_programs(model, geom, attn_fn):
             else:
                 y = mlpm.mlp_forward(p["mlp"], h2, cfg)
             pool = write_prefill_kv(pool, rk[:nblk_p],
-                                    k.reshape(nblk_p, -1))
+                                    k.reshape(nblk_p, ps, *k.shape[2:]))
             pool = write_prefill_kv(pool, rv[:nblk_p],
-                                    v.reshape(nblk_p, -1))
+                                    v.reshape(nblk_p, ps, *v.shape[2:]))
             return (x + y, pool), None
 
         (x, pool), _ = jax.lax.scan(layer, (x, pool),
@@ -303,7 +300,6 @@ def _build_hybrid_programs(model, geom, attn_fn, layout):
 
     def token(params, pool, state, tokens, pos, rows_k, rows_v, active):
         """One token for the whole batch: state leaves (L, B, ...)."""
-        B = tokens.shape[0]
         x = mapi._embed_lookup(params["embed"], tokens[:, None], dtype,
                                cfg.embed_impl)
         sh = params["shared_attn"]
@@ -324,10 +320,10 @@ def _build_hybrid_programs(model, geom, attn_fn, layout):
                 h = rms_norm(x, sh["norm"], eps)
                 q, k, v = attn.project_qkv(sh["attn"], h, h, cfg,
                                            positions, positions, True)
-                pool = write_token_kv(pool, rk, blk, off,
-                                      k[:, 0].reshape(B, -1), active)
-                pool = write_token_kv(pool, rv, blk, off,
-                                      v[:, 0].reshape(B, -1), active)
+                pool = write_token_kv(pool, rk, blk, off, k[:, 0],
+                                      active)
+                pool = write_token_kv(pool, rv, blk, off, v[:, 0],
+                                      active)
                 a = attn_fn(q[:, 0], pool, rk, rv, lengths)
                 y = attn.output_proj(sh["attn"], a[:, None].astype(x.dtype))
                 return x + y, pool

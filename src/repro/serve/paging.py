@@ -2,16 +2,18 @@
 
 ONE f32 pool ``(n_pages, page_elems)`` holds every per-request cache:
 
-  - KV pages: page row j of request b stores ``page_size`` tokens x
-    ``n_kv`` heads x ``head_dim`` floats for one layer's K (or V), laid
-    out token-major — exactly what the decode kernel
-    (``kernels/decode_attention.py``) streams per grid step.
+  - KV pages: page row j of request b stores ``page_size`` tokens of
+    one layer's K (or V), head-major: ``n_kv`` slabs of ``(rows,
+    head_dim)`` floats (``decode_attention.page_view``), one of which the
+    decode kernel (``kernels/decode_attention.py``) streams per grid
+    step.
   - Recurrent-state rows: a slot's packed xLSTM/Mamba state (one flat
     buffer via ``optim/packing``) is split into ``page_elems``-wide rows
     (``packing.pad_rows``) and scattered to its own pool rows.
 
-``page_elems`` is rounded up to a multiple of 256 — the same chunk
-quantum the int8 codec and ``shard_layout`` use — so pool rows stay
+``page_elems`` is ``n_kv * rows * head_dim`` with ``rows >= page_size``
+and a multiple of 256 — the same chunk quantum the int8
+codec and ``shard_layout`` use — so pool rows stay
 whole-chunk-aligned and a future sharded pool splits on the same
 boundaries as the train-side wire buffers (ISSUE 9 tentpole).
 
@@ -28,11 +30,14 @@ mid-flight OOM path.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.decode_attention import page_view
 
 ALIGN = 256        # chunk quantum shared with the int8 codec / shard_layout
 TRASH_ROW = 0      # reserved pool row for masked/inactive traffic
@@ -75,8 +80,13 @@ def make_geom(*, page_size: int, n_kv: int, head_dim: int,
     the state-row split, and enough rows for ``n_slots + slack_slots``
     concurrent requests (or an explicit ``n_pages`` override, used by the
     backpressure test to force a tight pool)."""
-    kv_elems = page_size * n_kv * head_dim
-    page_elems = _round_up(max(kv_elems, 1), ALIGN)
+    width = n_kv * head_dim
+    page_elems = ALIGN
+    if width:
+        # n_kv head slabs of `rows` tokens each (rows >= page_size), in
+        # whole ALIGN chunks
+        page_elems = width * _round_up(page_size,
+                                       ALIGN // math.gcd(width, ALIGN))
     max_blocks = -(-max_len // page_size) if n_layers_kv else 0
     state_rows = -(-state_size // page_elems) if state_size else 0
     geom = PageGeom(page_size=page_size, n_kv=n_kv, head_dim=head_dim,
@@ -118,7 +128,7 @@ def write_token_kv(pool, rows, blk, off, vec, valid=None):
     """Scatter one decode step's per-slot K (or V) vectors into the pool.
 
     pool (n_pages, E); rows (B, nblk) page table for ONE layer's K or V;
-    blk/off (B,) int32 block index / in-page offset; vec (B, n_kv*hd)
+    blk/off (B,) int32 block index / in-page offset; vec (B, n_kv, hd)
     f32; valid (B,) bool or None. Invalid slots write to the trash row
     at offset 0 — garbage that nothing reads (their table rows also point
     at trash, and length masking hides position 0 overwrites).
@@ -127,19 +137,25 @@ def write_token_kv(pool, rows, blk, off, vec, valid=None):
     if valid is not None:
         row = jnp.where(valid, row, TRASH_ROW)
         off = jnp.where(valid, off, 0)
-    width = vec.shape[-1]
-    cols = off[:, None] * width + jnp.arange(width, dtype=jnp.int32)[None]
-    return pool.at[row[:, None], cols].set(vec.astype(pool.dtype))
+    _, n_kv, hd = vec.shape
+    heads = jnp.arange(n_kv, dtype=jnp.int32)[None]
+    pages = page_view(pool, n_kv, hd).at[row[:, None], heads,
+                                         off[:, None]].set(
+        vec.astype(pool.dtype))
+    return pages.reshape(pool.shape)
 
 
-def write_prefill_kv(pool, rows, mat):
+def write_prefill_kv(pool, rows, kv):
     """Scatter a whole prefill's pages for one layer's K (or V).
 
-    rows (nblk,) page table of the single prefilling slot; mat
-    (nblk, page_size * n_kv * hd) f32, token-major per page. Rows past
-    the prompt length still land on real (allocated) pages — their
+    rows (nblk,) page table of the single prefilling slot; kv (nblk,
+    page_size, n_kv, hd) f32, the prompt's tokens block by block. Rows
+    past the prompt length still land on real (allocated) pages — their
     garbage is hidden by length masking in the kernel."""
-    return pool.at[rows, :mat.shape[-1]].set(mat.astype(pool.dtype))
+    _, ps, n_kv, hd = kv.shape
+    pages = page_view(pool, n_kv, hd).at[rows, :, :ps].set(
+        jnp.swapaxes(kv, 1, 2).astype(pool.dtype))
+    return pages.reshape(pool.shape)
 
 
 def read_state(pool, rows, size: int):

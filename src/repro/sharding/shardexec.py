@@ -54,7 +54,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comm import faults as faults_mod
@@ -148,7 +148,7 @@ class ShardExec:
             sspec = {k: (P() if k == "count" else spec) for k in opt_state}
             f = shard_map(opt.step, mesh=self.mesh,
                           in_specs=(spec, spec, sspec),
-                          out_specs=(spec, sspec), check_rep=False)
+                          out_specs=(spec, sspec), check_vma=False)
             return f(buf_G, grads_G, opt_state)
 
         return step
@@ -172,7 +172,7 @@ class ShardExec:
             return jax.lax.psum(part, sax)
 
         return shard_map(local, mesh=self.mesh, in_specs=(spec,),
-                         out_specs=self.group_spec(), check_rep=False)
+                         out_specs=self.group_spec(), check_vma=False)
 
     def consensus_sq_groups(self, use_pallas: bool):
         """Per-group consensus distance ||x_g - x̄||² of a (G, Np) buffer:
@@ -197,7 +197,7 @@ class ShardExec:
             return jax.lax.psum(part, sax)
 
         return shard_map(local, mesh=self.mesh, in_specs=(spec,),
-                         out_specs=self.group_spec(), check_rep=False)
+                         out_specs=self.group_spec(), check_vma=False)
 
     # -- codec-free mixing ------------------------------------------------
 
@@ -222,7 +222,7 @@ class ShardExec:
             return y
 
         return shard_map(local, mesh=self.mesh, in_specs=(spec,),
-                         out_specs=spec, check_rep=False)
+                         out_specs=spec, check_vma=False)
 
     def mix_streams(self, exch):
         """``Exchange.mix_inflight`` on sharded buffers (overlap mode,
@@ -570,7 +570,7 @@ class ShardExec:
                           out_specs=((spec,) * len(names),
                                      tuple(res_specs),
                                      tuple(pushed_specs)),
-                          check_rep=False)
+                          check_vma=False)
             mixed_t, new_res, new_pushed = f(
                 tuple(xs[k] for k in names), x0s, tuple(us), tuple(res),
                 tuple(pushed), fm, rnd)
@@ -710,7 +710,7 @@ class ShardExec:
                           out_specs=((spec,) * len(names),
                                      (bl_spec,) * len(names),
                                      gspec, blw_spec),
-                          check_rep=False)
+                          check_vma=False)
             mixed_t, new_bl, new_mass, new_blw = f(
                 tuple(xs[k] for k in names),
                 tuple(comm_state["backlog"][k] for k in names),
@@ -1021,7 +1021,7 @@ class ShardExec:
                           out_specs=((spec,) * len(names),
                                      tuple(bl_specs), mass_spec,
                                      blw_spec),
-                          check_rep=False)
+                          check_vma=False)
             mixed_t, new_bl, new_mass, new_blw = f(
                 tuple(xs[k] for k in names), x0s, tuple(us), tuple(bls),
                 act_i, masksA, delivA, denA, mass, blw, act_pod, incsB,
@@ -1150,7 +1150,7 @@ class ShardExec:
                                     tuple(us_specs), tuple(res_specs)),
                           out_specs=((spec,) * len(names),
                                      tuple(res_specs)),
-                          check_rep=False)
+                          check_vma=False)
             out_t, new_res = f(tuple(xs[k] for k in names), x0s,
                                tuple(us), tuple(res))
             for i, k in enumerate(names):
@@ -1178,19 +1178,25 @@ class ShardExec:
 
 def plan_for(mesh: Mesh, require: bool = False,
              hop_impl: str = "ppermute") -> Optional[ShardExec]:
-    """The mesh's sharded-execution plan, or None when no in-group axis
-    has more than one device (the replicated path is then both correct
-    and free — nothing to shard over). ``hop_impl`` selects the
-    ring/gossip hop collective (DESIGN.md §11)."""
-    shard_axes = tuple(a for a in SHARD_AXES
-                       if a in mesh.axis_names and mesh.shape[a] > 1)
+    """The mesh's sharded-execution plan, or None when neither the group
+    axes nor an in-group axis has more than one device (the replicated
+    path is then both correct and free — nothing to place). A mesh whose
+    only axis larger than 1 is a group axis gets a plan with one shard
+    per group: each device holds its group's whole buffer. ``hop_impl``
+    selects the ring/gossip hop collective (DESIGN.md §11)."""
+    group_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    in_group = tuple(a for a in SHARD_AXES if a in mesh.axis_names)
+    shard_axes = tuple(a for a in in_group if mesh.shape[a] > 1)
+    if not shard_axes and any(mesh.shape[a] > 1 for a in group_axes):
+        # groups on separate devices, each holding its whole buffer: one
+        # in-group axis of size 1 keeps the (G, Np) spec two-dimensional
+        shard_axes = in_group[:1]
     if not shard_axes:
         if require:
             raise ValueError(
-                f"mesh {dict(mesh.shape)} has no in-group axis "
-                f"({'/'.join(SHARD_AXES)}) larger than 1 to shard the "
-                "packed buffer over")
+                f"mesh {dict(mesh.shape)} has no group axis and no "
+                f"in-group axis ({'/'.join(SHARD_AXES)}) larger than 1 "
+                "to place the packed buffer over")
         return None
-    group_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     return ShardExec(mesh=mesh, group_axes=group_axes,
                      shard_axes=shard_axes, hop_impl=hop_impl)

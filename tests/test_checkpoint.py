@@ -90,4 +90,4 @@ def test_roundtrip_packed_state_with_comm_streams(tmp_path, key):
     res, mr = rnd(jax.tree.map(jnp.asarray, loaded), batch)
     for a, b in zip(jax.tree.leaves(cont), jax.tree.leaves(res)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert int(mc["wire_bytes"]) == int(mr["wire_bytes"])
+    assert rnd.wire_bytes(st) == rnd.wire_bytes(loaded)

@@ -386,6 +386,7 @@ def test_round_metrics_report_wire_bytes(key):
     st = lsgd.init_state(params, opt, n_groups=G, layout=layout,
                          exchange=ex)
     _, m = rnd(st, batch)
+    m = rnd.wire_bytes(st)
     # adamw: m and v buffers averaged at fp32; count not exchanged
     assert int(m["wire_bytes"]) == ex.wire_bytes_per_round(n, 2 * n)
     assert int(m["wire_bytes_up"]) == ex.wire_bytes_up(n, 2 * n)
@@ -396,7 +397,9 @@ def test_round_metrics_report_wire_bytes(key):
     # (it is not exchanged on either path); server up == down
     opt_t = optim.momentum(0.05)
     rnd_t = jax.jit(lsgd.make_local_round(quad_loss, opt_t, cfg))
-    _, mt = rnd_t(lsgd.init_state(params, opt_t, n_groups=G), batch)
+    st_t = lsgd.init_state(params, opt_t, n_groups=G)
+    rnd_t(st_t, batch)
+    mt = rnd_t.wire_bytes(st_t)
     assert int(mt["wire_bytes_up"]) == 4 * G * (n + n)
     assert int(mt["wire_bytes"]) == 2 * 4 * G * (n + n)
 
@@ -656,6 +659,7 @@ def test_moment_codec_round_metrics_per_stream(key):
     st = lsgd.init_state(params, opt, n_groups=G, layout=layout,
                          exchange=ex)
     _, m = rnd(st, batch)
+    m = rnd.wire_bytes(st)
     by = ex.wire_bytes_by_stream(n, {"m": n, "v": n})
     for k, v in by.items():
         assert int(m[f"wire_bytes/{k}"]) == v, k
@@ -765,6 +769,7 @@ def test_flat_only_moment_codec_needs_layout(key):
                                         cfg, exchange=ex))
     st = lsgd.init_state(params, optim.momentum(0.05), n_groups=G)
     out, m = rnd(st, batch)
+    m = {**m, **rnd.wire_bytes(st)}
     assert bool(jnp.all(jnp.isfinite(jax.tree.leaves(out["opt"]["mu"])[0])))
     # bf16 moments halve the moment wire term in the metrics
     n = sum(l.size for l in jax.tree.leaves(params))
@@ -815,5 +820,5 @@ def test_async_avg_opt_state_converges(s_stale, key):
     # amortized senders G/(s+1) times the fp32 moment payload, up+down
     n = layout.size
     want = 2 * int(round(G / (s_stale + 1) * 4 * n))
-    assert int(m["wire_bytes/mu"]) == want
+    assert rnd.wire_bytes(st)["wire_bytes/mu"] == want
     assert ex.wire_bytes_by_stream(n, {"mu": n})["mu"] == want

@@ -303,6 +303,7 @@ def test_downlink_round_level_accounting_and_clamp(key):
     assert set(st["comm"]["down"]) == {"params", "m", "v"}
     for _ in range(3):
         st, m = rnd(st, batch)
+    m = rnd.wire_bytes(st)
     n = layout.padded
     sizes = {k: n for k in opt.moment_keys}
     assert int(m["wire_bytes_down"]) == ex.wire_bytes_down(

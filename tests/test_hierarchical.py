@@ -423,6 +423,7 @@ def test_round_metrics_carry_tier_keys_and_wire_identity(key):
     rnd = jax.jit(lsgd.make_local_round(quad_loss, opt, cfg, exchange=ex))
     st = lsgd.init_state(params, opt, n_groups=G, exchange=ex)
     st, m = rnd(st, batch)
+    m = {**m, **rnd.wire_bytes(st)}
     assert set(obs.round_metric_keys(("params",))) <= set(m)
     assert int(m["wire_bytes"]) \
         == int(m["wire_bytes_intra"]) + int(m["wire_bytes_inter"])
@@ -442,6 +443,7 @@ def test_round_metrics_carry_tier_keys_and_wire_identity(key):
                                           exchange=ex_flat))
     st_f = lsgd.init_state(params, opt, n_groups=G, exchange=ex_flat)
     _, mf = rnd_f(st_f, batch)
+    mf = {**mf, **rnd_f.wire_bytes(st_f)}
     assert set(obs.round_metric_keys(("params",))) <= set(mf)
     assert int(mf["wire_bytes_intra"]) == int(mf["wire_bytes"])
     assert int(mf["wire_bytes_inter"]) == 0

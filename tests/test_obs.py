@@ -139,7 +139,7 @@ def _run_round(key, topo, codec, opt_name="sgd", packed=True, avg=False,
                          exchange=ex, average_opt_state=avg)
     for _ in range(rounds):
         st, m = rnd(st, batch)
-    return ex, st, m
+    return ex, st, {**m, **rnd.wire_bytes(st)}
 
 
 @pytest.mark.parametrize("topo,codec,opt_name,avg,faults", [
@@ -248,7 +248,7 @@ def test_trace_roundtrip_faulty_push_sum(key, tmp_path):
         for n in range(4):
             with tr.phase("round") as f:
                 st, m = f(rnd(st, batch))
-            tr.emit_round(n, m)
+            tr.emit_round(n, {**m, **rnd.wire_bytes(st)})
     meta, records = report.load(path)
     assert report.check(meta, records) == []
     assert meta["schema"] == obs.SCHEMA_VERSION
@@ -293,6 +293,42 @@ def test_report_check_flags_broken_traces(tmp_path):
     bad_split = dict(rec, metrics=dict(m_ok, wire_bytes=999))
     assert any("per-stream splits" in s
                for s in write(p, [meta, bad_split]))
+
+
+def test_full_width_round_wire_counts_stay_exact(tmp_path):
+    """paper-lenet at full width, G=4, momentum: one round moves
+    7,978,401,792 wire bytes — past int32, where tracing the round used
+    to die with an OverflowError. The round traces (abstractly, nothing
+    allocated), its host-side counts are exact ints, and a record
+    carrying them passes report --check."""
+    from repro.configs.base import get_config
+    from repro.models import build_model
+
+    model = build_model(get_config("paper-lenet"), schedule="rect")
+    pabs = model.abstract()
+    layout = packing.layout_of(pabs)
+    opt = optim.packed("momentum", 0.05, impl="jnp")
+    ex = comm.get_exchange("server", "fp32", G)
+    rnd = lsgd.make_local_round(
+        model.loss, opt, lsgd.LocalSGDConfig(n_groups=G, inner_steps=1),
+        layout=layout, exchange=ex)
+    st = jax.eval_shape(lambda p: lsgd.init_state(
+        p, opt, n_groups=G, layout=layout, exchange=ex), pabs)
+    batch = {"tokens": jax.ShapeDtypeStruct((G, 1, 8), jnp.int32)}
+    _, m = jax.eval_shape(rnd, st, batch)
+    assert not any(k.startswith("wire_bytes") for k in m)
+    wire = rnd.wire_bytes(st)
+    # server fp32: G pushes + G replies of params + mu, 4 bytes each
+    assert wire["wire_bytes"] == 2 * G * 4 * 2 * layout.size \
+        == 7_978_401_792
+    rec = {k: 1.0 for k in obs.round_metric_keys(("params", "mu"))}
+    rec.update(wire)
+    path = tmp_path / "full.jsonl"
+    with obs.Trace(str(path), meta={"arch": "paper-lenet"}) as tr:
+        with tr.phase("round"):
+            pass
+        tr.emit_round(0, rec)
+    assert report.check(*report.load(path)) == []
 
 
 def test_trace_null_sink_still_times(key):
@@ -393,7 +429,7 @@ def test_consensus_trajectory_parity_replicated_vs_sharded(key, tmp_path):
             for n in range(4):
                 with tr.phase("round") as f:
                     st, m = f(rnd(st, batch))
-                tr.emit_round(n, m)
+                tr.emit_round(n, {**m, **rnd.wire_bytes(st)})
         meta, records = report.load(path)
         assert report.check(meta, records) == []
         traces[tag] = report.summarize(meta, records)

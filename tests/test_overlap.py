@@ -645,7 +645,7 @@ def test_sharded_overlap_matches_replicated(topology, codec, key):
         np.asarray(st_s["comm"]["inflight"]["params"]),
         np.asarray(st_r["comm"]["inflight"]["params"]),
         rtol=1e-5, atol=1e-6)
-    assert int(ms["wire_bytes"]) == int(mr["wire_bytes"])
+    assert rnd_s.wire_bytes(st_s) == rnd_r.wire_bytes(st_r)
 
 
 # ---------------------------------------------------------------------------

@@ -313,6 +313,7 @@ def test_final_metrics_contract(key):
                                         layout=layout))
     sp = lsgd.init_state(params, opt_p, n_groups=G, layout=layout)
     new_sp, m = rnd(sp, batch)
+    m = {**m, **rnd.wire_bytes(sp)}
     from repro import obs
     assert set(m) == set(obs.round_metric_keys(("params",)))
     # per-stream split sums to the old total (sgd: params only)

@@ -75,7 +75,7 @@ def test_token_kv_write_masks_to_trash():
                          max_len=4, state_size=0, n_slots=2)
     pool = g.pool()
     rows = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
-    vec = jnp.ones((2, 4), jnp.float32)       # n_kv * head_dim = 4
+    vec = jnp.ones((2, 1, 4), jnp.float32)    # (B, n_kv, head_dim)
     blk = jnp.asarray([0, 1], jnp.int32)
     off = jnp.asarray([1, 0], jnp.int32)
     out = paging.write_token_kv(pool, rows, blk, off, vec,

@@ -461,10 +461,10 @@ def test_build_packed_train_step_sharded(key):
 
 @needs8
 def test_sharded_matches_replicated_builder_end_to_end(key):
-    """Same config, same mesh: the sharded builder's round and a
-    replicated-fallback round (jnp, data-axis-only mesh) produce the same
-    server params after a round, <= 1e-5 rel — the builder-level version
-    of the parity gate."""
+    """Same config: the sharded builder's round (2 shards per group,
+    Pallas) and the group-only placement (a data-axis-only mesh: one
+    whole buffer per device, jnp) produce the same server params after a
+    round, <= 1e-5 rel — the builder-level version of the parity gate."""
     from jax.sharding import Mesh
     from repro.configs.base import InputShape, get_config
     from repro.launch.steps import build_train_step
@@ -480,15 +480,15 @@ def test_sharded_matches_replicated_builder_end_to_end(key):
                             ("replicated", mesh_r, "jnp")):
         built = build_train_step(cfg, shape, mesh, t_inner=2,
                                  opt_name="sgd", packed=True, impl=impl)
-        assert built.meta["sharded"] == (tag == "sharded")
+        assert built.meta["sharded"]
+        assert built.meta["n_shards"] == (2 if tag == "sharded" else 1)
         state_abs, batch_abs = built.args
         rng = np.random.RandomState(0)
         from repro.models import build_model
         model = build_model(cfg, schedule="rect")
         params = model.init(jax.random.PRNGKey(0))
-        layout = packing.layout_of(params)
-        if built.meta["sharded"]:
-            layout = packing.shard_layout(layout, built.meta["n_shards"])
+        layout = packing.shard_layout(packing.layout_of(params),
+                                      built.meta["n_shards"])
         opt = optim.get("sgd", 1e-3, packed=True, impl=impl)
         state = lsgd.init_state(params, opt, n_groups=4, layout=layout)
         batch = {"tokens": jnp.asarray(
