@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device
+(1 - busy union / window), averaged over the chips."""
+
+
+def read(ctx):
+    r = ctx["reduced"]
+    if ctx.get("kind") != "train" or r["window_s"] <= 0 or r["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - r["busy_s"] / r["window_s"])
