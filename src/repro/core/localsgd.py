@@ -605,7 +605,8 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
 
         def body(state, t, batch_t):
             loss_G, g_G = jax.vmap(flat_vg)(state["params"], batch_t)
-            new_p, new_o = opt_step(state["params"], g_G, state["opt"])
+            with jax.named_scope("opt_update"):
+                new_p, new_o = opt_step(state["params"], g_G, state["opt"])
             if t_vec is not None:
                 keep = (t < t_vec)[:, None]           # (G, 1)
                 new_p = jnp.where(keep, new_p, state["params"])

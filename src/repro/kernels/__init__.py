@@ -38,6 +38,21 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
+def named_pallas_call(name: str, kernel, **kwargs):
+    """``pl.pallas_call`` under a stable name: the kernel is built with
+    ``name=name`` and each call runs inside ``jax.named_scope(name)``, so
+    the device trace finds the kernel by its name (its op's ``tf_op``
+    ends in ``<name>/pallas_call``) and not by its operand shapes."""
+    from jax.experimental import pallas as pl
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def run(*args):
+        with _jax.named_scope(name):
+            return call(*args)
+
+    return run
+
+
 def as_rows(x):
     """A packed buffer as the 2-D (rows, N) view the flat-buffer kernels
     take: a 1-D buffer is one row, the (G, N) grouped one is itself."""

@@ -63,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import named_pallas_call
+
 NEG_INF = -1e30
 
 
@@ -186,7 +188,8 @@ def paged_decode_attention(q, pool, rows_k, rows_v, lengths, *,
             pltpu.VMEM((g, hd), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "decode_attention",
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, n_kv, g, hd), jnp.float32),

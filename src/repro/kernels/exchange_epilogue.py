@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import named_pallas_call
 from repro.kernels.quantize import row_block
 
 KINDS = ("int8", "bf16", "fp16", "thresh")
@@ -203,9 +204,9 @@ def codec_mix(x, x0, *, kind: str, u=None, w=None, hops: int = 1,
         if ef:
             outs[1][...] = res_out
 
-    out = pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
-                         out_specs=out_specs, out_shape=out_shape,
-                         interpret=interpret)(*args)
+    out = named_pallas_call("exchange_epilogue", kernel, grid=grid,
+                            in_specs=in_specs, out_specs=out_specs,
+                            out_shape=out_shape, interpret=interpret)(*args)
     if ef:
         mixed, res_out = out
         return mixed[:, :n], res_out[:, :n]
@@ -233,7 +234,8 @@ def qdq_int8(x, u, *, interpret: bool = True):
     bit-identical)."""
     rows, chunk = x.shape
     rb = row_block(rows)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "qdq_int8",
         _qdq_kernel,
         grid=(pl.cdiv(rows, rb),),
         in_specs=[pl.BlockSpec((rb, chunk), lambda i: (i, 0)),

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import named_pallas_call
+
 NEG_INF = -1e30
 
 
@@ -81,7 +83,8 @@ def flash_attention(q, k, v, *, block_q: int = 128, block_k: int = 128,
         _kernel, block_q=block_q, block_k=block_k,
         scale=1.0 / (hd ** 0.5), nk=nk)
 
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "flash_attention",
         kernel,
         grid=(bh, nq, nk),
         in_specs=[

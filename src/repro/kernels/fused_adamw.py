@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import as_rows, flat_blocks
+from repro.kernels import as_rows, flat_blocks, named_pallas_call
 
 
 def _kernel(p_ref, g_ref, m_ref, v_ref, bc_ref, po_ref, mo_ref, vo_ref,
@@ -40,7 +40,8 @@ def fused_adamw(p, g, m, v, *, count, lr, b1=0.9, b2=0.999, eps=1e-8,
     p2 = as_rows(p)
     bs, grid = flat_blocks(p2.shape, block)
     spec = pl.BlockSpec(bs, lambda i: (0, i))
-    outs = pl.pallas_call(
+    outs = named_pallas_call(
+        "fused_adamw",
         functools.partial(_kernel, lr=lr, b1=b1, b2=b2, eps=eps, wd=wd),
         grid=grid,
         in_specs=[spec, spec, spec, spec,

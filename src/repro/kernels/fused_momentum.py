@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import as_rows, flat_blocks
+from repro.kernels import as_rows, flat_blocks, named_pallas_call
 
 
 def _kernel(p_ref, g_ref, mu_ref, po_ref, muo_ref, *, lr, beta):
@@ -31,7 +31,8 @@ def fused_momentum(p, g, mu, *, lr, beta=0.9, block: int = 65536,
     p2 = as_rows(p)
     bs, grid = flat_blocks(p2.shape, block)
     spec = pl.BlockSpec(bs, lambda i: (0, i))
-    new_p, new_mu = pl.pallas_call(
+    new_p, new_mu = named_pallas_call(
+        "fused_momentum",
         functools.partial(_kernel, lr=lr, beta=beta),
         grid=grid,
         in_specs=[spec, spec, spec],

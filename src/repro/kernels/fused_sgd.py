@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import as_rows, flat_blocks
+from repro.kernels import as_rows, flat_blocks, named_pallas_call
 
 
 def _kernel(p_ref, g_ref, po_ref, *, lr):
@@ -26,7 +26,8 @@ def fused_sgd(p, g, *, lr, block: int = 65536, interpret: bool = True):
     p2 = as_rows(p)
     bs, grid = flat_blocks(p2.shape, block)
     spec = pl.BlockSpec(bs, lambda i: (0, i))
-    new_p = pl.pallas_call(
+    new_p = named_pallas_call(
+        "fused_sgd",
         functools.partial(_kernel, lr=lr),
         grid=grid,
         in_specs=[spec, spec],

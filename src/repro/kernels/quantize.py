@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import named_pallas_call
+
 
 # chunk rows per grid step: a multiple of 32 (the int8 sublane tile, and
 # so of the (8, 128) block rule) and 512 KiB of f32 per operand block
@@ -50,7 +52,8 @@ def quantize_int8(x, u, *, interpret: bool = True):
     scales f32 (rows, 1)); one scale per row."""
     rows, chunk = x.shape
     rb = row_block(rows)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "quantize_int8",
         _quantize_kernel,
         grid=(pl.cdiv(rows, rb),),
         in_specs=[
@@ -77,7 +80,8 @@ def dequantize_int8(q, scales, *, interpret: bool = True):
     """(rows, chunk) int8 + (rows, 1) scales -> (rows, chunk) f32."""
     rows, chunk = q.shape
     rb = row_block(rows)
-    return pl.pallas_call(
+    return named_pallas_call(
+        "dequantize_int8",
         _dequantize_kernel,
         grid=(pl.cdiv(rows, rb),),
         in_specs=[

@@ -7,6 +7,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import named_pallas_call
+
 
 def _kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)                 # (rows, D)
@@ -31,7 +33,8 @@ def rmsnorm(x, w, eps: float = 1e-5, block_rows: int = 128,
         xf = jnp.pad(xf, ((0, pad), (0, 0)))
     n = xf.shape[0] // block_rows
 
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "rmsnorm",
         functools.partial(_kernel, eps=eps),
         grid=(n,),
         in_specs=[

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import named_pallas_call
+
 
 def sq_norm(x, *, block: int = 65536, interpret: bool = True) -> jax.Array:
     """Sum of squares of a flat 1-D array -> f32 scalar."""
@@ -49,7 +51,8 @@ def sq_norm_groups(x, *, block: int = 65536,
     g, n = x.shape
     block = n if n <= block else block
     kernel = functools.partial(_kernel_groups, n=n, block=block)
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "sq_norm",
         kernel,
         grid=(pl.cdiv(n, block),),
         in_specs=[pl.BlockSpec((g, block), lambda j: (0, j))],

@@ -37,14 +37,6 @@ ROUND_KEYS = (
     "delivery_rate_intra", "delivery_rate_inter",
 )
 
-# host-measured phase names the launchers emit (checkpoint only appears
-# on rounds that save one; the exchange_* pair appears on calibrated
-# localsgd runs — trace.exchange_phases, DESIGN.md §14: "exposed" is the
-# exchange time on the round's critical path, "total" what the exchange
-# costs standalone; overlap efficiency = 1 - exposed/total)
-PHASES = ("data", "round", "step", "checkpoint",
-          "exchange_exposed", "exchange_total")
-
 
 def round_metric_keys(streams=("params",)):
     """The full uniform key set for a round exchanging ``streams``."""

@@ -203,10 +203,10 @@ def exchange_phases(round_s: float, local_ref_s: float, exch_ref_s: float,
 @contextlib.contextmanager
 def profile_span(path: Optional[str]):
     """Wrap a region in ``jax.profiler.start_trace`` (perfetto dump under
-    ``path``); no-op when path is falsy. Profiler caveat (DESIGN.md §13):
-    device annotations inside shard_map/jit come from XLA op metadata,
-    so the host-side TraceAnnotations are the reliable phase boundaries
-    on CPU."""
+    ``path``); no-op when path is falsy. On a TPU each device op carries
+    its ``jax.named_scope`` path as the ``tf_op`` stat of its event
+    metadata (DESIGN.md §13; ``bench/trace_scopes.py`` reads it); the
+    host-side TraceAnnotations mark the phases on the host's clock."""
     if not path:
         yield
         return

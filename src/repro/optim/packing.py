@@ -263,11 +263,18 @@ def value_and_flat_grad(loss_fn, layout: Layout):
 
     Differentiates w.r.t. the UNPACKED tree and packs the grads (one
     concatenate) — never w.r.t. the buffer itself (see module docstring).
+    The three parts run under the named scopes ``unpack``, ``fwd_bwd``
+    and ``grad_pack``, which the profiler's trace attributes device time
+    to (DESIGN.md §13).
     """
     vg = jax.value_and_grad(loss_fn)
 
     def flat_vg(buf, batch):
-        loss, g_tree = vg(unpack_for_compute(buf, layout), batch)
-        return loss, pack(g_tree, layout)
+        with jax.named_scope("unpack"):
+            tree = unpack_for_compute(buf, layout)
+        with jax.named_scope("fwd_bwd"):
+            loss, g_tree = vg(tree, batch)
+        with jax.named_scope("grad_pack"):
+            return loss, pack(g_tree, layout)
 
     return flat_vg
