@@ -14,8 +14,9 @@ One chip:
            batch 4 x 256 tokens, server/fp32) that must give finite,
            falling losses and a compiled round holding Pallas TPU kernels;
            one int8-codec round; one round with --impl pallas and one with
-           --impl jnp from the same seed, whose params must agree within
-           PARITY_TOL.
+           --impl jnp from the same seed (T=1, so the round keeps the flat
+           buffers and the fused update kernel runs), whose params must
+           agree within PARITY_TOL.
   serve    the trainer's checkpoint through the continuous-batching engine
            (Pallas decode attention), replaying every request in isolation
            (--check-parity).
@@ -139,8 +140,10 @@ def phase_train() -> dict:
     print("== trainer: 1 round --impl pallas vs --impl jnp", flush=True)
     params = {}
     for impl in ("pallas", "jnp"):
+        # T=1 < 2 streams: the round keeps the flat buffers, where the
+        # fused update kernel runs (DESIGN.md §6)
         run = train.main(TRAIN + ["--rounds", "1", "--codec", "fp32",
-                                  "--impl", impl])
+                                  "--impl", impl, "--t-inner", "1"])
         params[impl] = _host_params(run)
     diff = _max_abs_diff(params["pallas"], params["jnp"])
     print(f"pallas vs jnp: max |param diff| {diff!r} (tolerance "

@@ -491,6 +491,31 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
 # ---------------------------------------------------------------------------
 
 
+def carries_leaves(n_streams: int, inner_steps: int, shardexec=None) -> bool:
+    """Whether a packed round carries the state's leaves through its local
+    steps instead of the flat (G, N) buffers.
+
+    On a TPU every crossing between a (G, N) buffer and the leaf tree is
+    a relayout of the whole buffer (DESIGN.md §6): U to unpack one, P to
+    pack one. Carrying the buffers crosses twice a local step (params
+    unpacked for the model, the gradient packed for the fused update)
+    and once more to evaluate the round's result: T(U+P) + U a round.
+    Carrying the leaves unpacks each of the S streams (params and the
+    optimizer's moments) once at entry and packs it once at exit:
+    S(U+P). So the leaves cost less exactly when S <= T. Under
+    ``shardexec`` the update runs on shard-local slices of the buffer
+    inside shard_map (DESIGN.md §9), so that round keeps the buffers."""
+    return shardexec is None and n_streams <= inner_steps
+
+
+def _where_groups(keep, new, old):
+    """Per-group select over leaves (or buffers) with a leading G axis:
+    ``new`` where ``keep`` (G,), else ``old``."""
+    return jax.tree.map(
+        lambda a, b: jnp.where(keep.reshape((-1,) + (1,) * (a.ndim - 1)),
+                               a, b), new, old)
+
+
 def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
                              cfg: LocalSGDConfig, layout: packing.Layout,
                              exch: "comm_mod.Exchange", shardexec=None):
@@ -511,6 +536,15 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
     path; per-step work is JUST the fused update, loss/||grad||^2 are
     evaluated once on the round's result) or "traj" (per-step
     trajectories, matching the pytree round's metrics exactly).
+
+    Where ``carries_leaves`` says so, the local steps carry the state as
+    float32 leaves instead: every stream is unpacked once at the round's
+    start (``state_unpack``) and packed once before the exchange
+    (``state_pack``), and each step updates the leaves with the packed
+    optimizer's elementwise formula. The round's inputs and outputs are
+    the same flat buffers either way. ``round_.buffer_path`` ("leaves" or
+    "flat") and ``round_.buffer_passes`` (whole-buffer crossings a round:
+    2S, or 2T plus one for the "final" evaluation) say which it took.
 
     Per-node t_i with a count-dependent update (adamw bias correction,
     lr schedules) runs the fused step vmapped over G with a PER-GROUP
@@ -538,8 +572,12 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             "count vector outside the shard_map opt step; run it on the "
             "replicated packed path (DESIGN.md §10)")
     use_pallas = getattr(opt, "impl", "jnp") == "pallas"
-    flat_vg = packing.value_and_flat_grad(loss_fn, layout)
     slayout = packing.stream_layout_for(opt, layout)
+    leaves = carries_leaves(slayout.n_streams, cfg.inner_steps, shardexec)
+    if leaves:
+        step_vg = packing.value_and_leaf_grad(loss_fn, layout)
+    else:
+        step_vg = packing.value_and_flat_grad(loss_fn, layout)
 
     exch_streams = mix_inflight = encode_streams = None
     if shardexec is not None:
@@ -552,18 +590,32 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
         gsq_groups = shardexec.sq_norm_groups(use_pallas)
         consensus_groups = shardexec.consensus_sq_groups(use_pallas)
     else:
-        opt_step = (jax.vmap(opt.step) if per_group_count else opt.step)
         if exch.overlap:
             mix_inflight = exch.mix_inflight
             encode_streams = exch.encode_streams
         else:
             exch_streams = exch.streams
 
-        def gsq_groups(g_G):
-            return _grad_sq_norm_groups(g_G, use_pallas)
-
         def consensus_groups(x_G):
             return _consensus_sq_flat(x_G, use_pallas)
+
+        if leaves:
+            def opt_step(p_G, g_G, o_G):
+                # one group's leaves a call, so a clipping wrapper takes
+                # each group's norm over all of its leaves; the count
+                # stays the shared scalar unless it is per-group
+                axes = {k: (None if k == "count" and not per_group_count
+                            else 0) for k in o_G}
+                return jax.vmap(opt.step, in_axes=(0, 0, axes),
+                                out_axes=(0, axes))(p_G, g_G, o_G)
+
+            gsq_groups = jax.vmap(grad_sq_norm)
+        else:
+            opt_step = (jax.vmap(opt.step) if per_group_count
+                        else opt.step)
+
+            def gsq_groups(g_G):
+                return _grad_sq_norm_groups(g_G, use_pallas)
 
     if cfg.t_i is not None:
         assert len(cfg.t_i) == cfg.n_groups, cfg.t_i
@@ -602,14 +654,21 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
                 mixed_inf = mix_inflight(inflight)
 
         traj = cfg.metrics == "traj"
+        if leaves:
+            def unpack32(buf):
+                return packing.unpack(buf, layout, jnp.float32)
+
+            with jax.named_scope("state_unpack"):
+                state_G = {"params": unpack32(state_G["params"]),
+                           "opt": map_moments(unpack32, state_G["opt"])}
 
         def body(state, t, batch_t):
-            loss_G, g_G = jax.vmap(flat_vg)(state["params"], batch_t)
+            loss_G, g_G = jax.vmap(step_vg)(state["params"], batch_t)
             with jax.named_scope("opt_update"):
                 new_p, new_o = opt_step(state["params"], g_G, state["opt"])
             if t_vec is not None:
-                keep = (t < t_vec)[:, None]           # (G, 1)
-                new_p = jnp.where(keep, new_p, state["params"])
+                keep = t < t_vec                      # (G,)
+                new_p = _where_groups(keep, new_p, state["params"])
                 old_o = state["opt"]
 
                 def mask(k, v):
@@ -617,9 +676,9 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
                     # convention) unless the update is count-dependent —
                     # then it is per-group and masks like the moments
                     if k == "count":
-                        return (jnp.where(t < t_vec, v, old_o[k])
+                        return (jnp.where(keep, v, old_o[k])
                                 if per_group_count else v)
-                    return jnp.where(keep, v, old_o[k])
+                    return _where_groups(keep, v, old_o[k])
 
                 new_o = {k: mask(k, v) for k, v in new_o.items()}
             new = {"params": new_p, "opt": new_o}
@@ -664,9 +723,10 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             # skipping the pack saves two full passes over the model.
             vg = jax.value_and_grad(loss_fn)
 
-            def final_eval(buf, b):
-                loss, g_tree = vg(packing.unpack_for_compute(buf, layout),
-                                  b)
+            def final_eval(x, b):
+                view = (packing.as_layout_dtypes(x, layout) if leaves
+                        else packing.unpack_for_compute(x, layout))
+                loss, g_tree = vg(view, b)
                 return loss, grad_sq_norm(g_tree)
 
             with jax.named_scope("final_eval"):
@@ -675,6 +735,12 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             metrics = {"loss": loss_G,
                        "inner_steps": n_steps,
                        "grad_sq": gsq_G}
+        if leaves:
+            with jax.named_scope("state_pack"):
+                state_G = {"params": packing.pack(state_G["params"], layout),
+                           "opt": map_moments(
+                               lambda x: packing.pack(x, layout),
+                               state_G["opt"])}
         # ---- communication: flat buffers through the stream exchange ----
         # every stream (params + averaged moments) rides its own codec;
         # the step counter is never exchanged (map_moments convention)
@@ -720,6 +786,10 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             out["comm"] = comm_state
         return out, metrics
 
+    round_.buffer_path = "leaves" if leaves else "flat"
+    round_.buffer_passes = (2 * slayout.n_streams if leaves
+                            else 2 * cfg.inner_steps
+                            + (cfg.metrics == "final"))
     return round_
 
 

@@ -390,6 +390,11 @@ def main(argv=None, devices=None) -> dict:
         local_ref_step = exch_ref_s = 0.0
         trace.meta.update({"comm": exchange.name,
                            "delivery_rate": exchange.delivery_rate})
+        if args.packed:
+            # where the round crosses between its flat buffers and the
+            # leaves, and how often (DESIGN.md §6)
+            trace.meta.update({"buffer_path": rnd.buffer_path,
+                               "buffer_passes": rnd.buffer_passes})
         with obs.profile_span(args.profile):
             for n in range(args.rounds):
                 with trace.phase("data"):

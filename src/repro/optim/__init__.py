@@ -115,7 +115,15 @@ def adamw(lr: float, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
 # of ~10 element-wise HLO ops per pytree leaf. Buffers may carry leading
 # axes (the local-SGD G axis); the kernels block the (G, N) buffer as it
 # is (the raveled form compiled to 3.2 GiB more temporaries for a TPU at
-# paper-lenet's width).
+# paper-lenet's width). A packed step also takes its state as trees of
+# float32 leaves, as the leaf-carrying round holds it (DESIGN.md §6):
+# then the same elementwise formula runs leaf by leaf, as the impl="jnp"
+# branch, and XLA fuses it per leaf.
+
+
+def _flat(buf) -> bool:
+    """Whether a packed step got the flat buffer (else a tree of leaves)."""
+    return isinstance(buf, jax.Array)
 
 
 def _resolve_impl(impl: str) -> str:
@@ -139,12 +147,12 @@ def packed_sgd(lr: float, *, impl: str = "auto") -> Optimizer:
         return {"count": jnp.zeros((), jnp.int32)}
 
     def step(buf, grads, state):
-        if impl == "pallas":
+        if impl == "pallas" and _flat(buf):
             from repro.kernels import use_interpret
             from repro.kernels.fused_sgd import fused_sgd
             new = fused_sgd(buf, grads, lr=lr, interpret=use_interpret())
         else:
-            new = buf - lr * grads
+            new = jax.tree.map(lambda p, g: p - lr * g, buf, grads)
         return new, {"count": state["count"] + 1}
 
     return Optimizer(init, step, "sgd", packed=True, impl=impl)
@@ -159,14 +167,14 @@ def packed_momentum(lr: float, beta: float = 0.9, *,
                 "mu": jnp.zeros_like(buf)}
 
     def step(buf, grads, state):
-        if impl == "pallas":
+        if impl == "pallas" and _flat(buf):
             from repro.kernels import use_interpret
             from repro.kernels.fused_momentum import fused_momentum
             new, mu = fused_momentum(buf, grads, state["mu"], lr=lr,
                                      beta=beta, interpret=use_interpret())
         else:
-            mu = beta * state["mu"] + grads
-            new = buf - lr * mu
+            mu = jax.tree.map(lambda m, g: beta * m + g, state["mu"], grads)
+            new = jax.tree.map(lambda p, m: p - lr * m, buf, mu)
         return new, {"count": state["count"] + 1, "mu": mu}
 
     return Optimizer(init, step, "momentum", packed=True, impl=impl,
@@ -185,7 +193,7 @@ def packed_adamw(lr: float, b1: float = 0.9, b2: float = 0.999,
 
     def step(buf, grads, state):
         c = state["count"] + 1
-        if impl == "pallas":
+        if impl == "pallas" and _flat(buf):
             from repro.kernels import use_interpret
             from repro.kernels.fused_adamw import fused_adamw
             new, m, v = fused_adamw(
@@ -196,10 +204,16 @@ def packed_adamw(lr: float, b1: float = 0.9, b2: float = 0.999,
             # so the packed path is bit-compatible up to fma reassociation.
             bc1 = 1.0 - b1 ** c.astype(jnp.float32)
             bc2 = 1.0 - b2 ** c.astype(jnp.float32)
-            m = b1 * state["m"] + (1 - b1) * grads
-            v = b2 * state["v"] + (1 - b2) * jnp.square(grads)
-            upd = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
-            new = buf - lr * (upd + weight_decay * buf)
+
+            def upd(p, m, v):
+                u = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+                return p - lr * (u + weight_decay * p)
+
+            m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g,
+                             state["m"], grads)
+            v = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * jnp.square(g),
+                             state["v"], grads)
+            new = jax.tree.map(upd, buf, m, v)
         return new, {"count": c, "m": m, "v": v}
 
     return Optimizer(init, step, "adamw", packed=True, impl=impl,
@@ -227,23 +241,21 @@ def clip_by_global_norm(opt: Optimizer, max_norm: float) -> Optimizer:
     Works for packed optimizers too: their grad buffer may carry leading
     group axes, so the norm is taken over the model (last) axis only —
     one norm per group, matching the pytree round's per-group clipping.
+    A tree of leaves (the pytree optimizers, and a packed step on one
+    group's leaves) takes one norm over every leaf.
     ``dataclasses.replace`` keeps the packed/impl routing flags."""
 
-    if opt.packed:
-        def step(buf, grads, state):
-            gn = jnp.sqrt(jnp.sum(jnp.square(grads.astype(jnp.float32)),
-                                  axis=-1, keepdims=True))
-            scale = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-12))
-            return opt.step(buf, grads * scale.astype(grads.dtype), state)
-    else:
-        def step(params, grads, state):
-            leaves = jax.tree.leaves(grads)
-            gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                              for g in leaves))
-            scale = jnp.minimum(1.0, max_norm / jnp.maximum(gn, 1e-12))
-            clipped = jax.tree.map(lambda g: g * scale.astype(g.dtype),
-                                   grads)
-            return opt.step(params, clipped, state)
+    def step(params, grads, state):
+        if opt.packed and _flat(grads):
+            gsq = jnp.sum(jnp.square(grads.astype(jnp.float32)), axis=-1,
+                          keepdims=True)
+        else:
+            gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                      for g in jax.tree.leaves(grads))
+        scale = jnp.minimum(1.0, max_norm / jnp.maximum(jnp.sqrt(gsq),
+                                                          1e-12))
+        clipped = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
+        return opt.step(params, clipped, state)
 
     return dataclasses.replace(opt, step=step, name=opt.name + "+clip")
 

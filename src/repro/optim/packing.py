@@ -14,11 +14,13 @@ to ``dtypes[i]``. The buffer dtype is always float32. Leading batch axes
 (the local-SGD G axis) stack as leading buffer axes: a G-grouped tree packs
 to ``(G, size)``.
 
-``unpack`` uses static slices (views inside an XLA fusion — no copy);
-``pack`` is one concatenate. Gradients w.r.t. the packed buffer are taken
-per-leaf and packed, NOT by differentiating through ``unpack`` — the
-transpose of a slice is a pad-to-N scatter, which would materialize one
-full-size buffer per leaf.
+``unpack`` is static slices and ``pack`` one concatenate; on a TPU each
+is a relayout of the whole buffer between the leaves' tiles and the
+buffer's (DESIGN.md §6), so the packed round crosses as rarely as it
+can. Gradients w.r.t. the packed buffer are taken per-leaf and packed,
+NOT by differentiating through ``unpack`` — the transpose of a slice is
+a pad-to-N scatter, which would materialize one full-size buffer per
+leaf.
 """
 from __future__ import annotations
 
@@ -199,19 +201,29 @@ def pack(tree, layout: Layout) -> jax.Array:
     return buf
 
 
-def unpack(buf: jax.Array, layout: Layout):
+def unpack(buf: jax.Array, layout: Layout, dtype=None):
     """Rebuild the pytree (original shapes/dtypes) from the flat buffer.
 
-    Extra leading axes on ``buf`` are carried onto every leaf. Slicing is
-    static, so XLA reads leaves as views of the buffer inside fusions.
+    Extra leading axes on ``buf`` are carried onto every leaf. ``dtype``
+    casts every leaf to it instead of its own dtype (the leaf-carrying
+    round keeps the buffer's float32).
     """
     lead = buf.shape[:-1]
     leaves = [
-        buf[..., o:o + s].reshape(lead + sh).astype(dt)
+        buf[..., o:o + s].reshape(lead + sh).astype(dtype or dt)
         for o, s, sh, dt in zip(layout.offsets, layout.sizes,
                                 layout.shapes, layout.dtypes)
     ]
     return jax.tree.unflatten(layout.treedef, leaves)
+
+
+def as_layout_dtypes(tree, layout: Layout):
+    """Cast every leaf of ``tree`` (float32 leaves from ``unpack(...,
+    dtype=jnp.float32)``) to its dtype in ``layout``: what ``unpack``
+    would have handed the model."""
+    leaves = layout.treedef.flatten_up_to(tree)
+    return jax.tree.unflatten(layout.treedef, [
+        l.astype(dt) for l, dt in zip(leaves, layout.dtypes)])
 
 
 def unpack_for_compute(buf: jax.Array, layout: Layout):
@@ -278,3 +290,22 @@ def value_and_flat_grad(loss_fn, layout: Layout):
             return loss, pack(g_tree, layout)
 
     return flat_vg
+
+
+def value_and_leaf_grad(loss_fn, layout: Layout):
+    """``vg(leaves, batch) -> (loss, grad_leaves)`` on float32 leaves.
+
+    ``value_and_flat_grad`` for a round that carries the leaves instead
+    of the buffer: the model sees each leaf in its layout dtype, and the
+    gradient comes back in float32, as ``pack`` would store it. The
+    forward and backward run under the named scope ``fwd_bwd``; there is
+    nothing to unpack or pack.
+    """
+    vg = jax.value_and_grad(loss_fn)
+
+    def leaf_vg(leaves, batch):
+        with jax.named_scope("fwd_bwd"):
+            loss, g_tree = vg(as_layout_dtypes(leaves, layout), batch)
+        return loss, jax.tree.map(lambda g: g.astype(jnp.float32), g_tree)
+
+    return leaf_vg

@@ -37,6 +37,13 @@ def make_problem(key, G=3, r=4, d=6):
     return params, batch
 
 
+def _flat_only(monkeypatch):
+    """Build the next rounds on the per-step flat path whatever the rule
+    says (where the fused kernels run, and the reference the
+    leaf-carrying round must match)."""
+    monkeypatch.setattr(lsgd, "carries_leaves", lambda *a, **k: False)
+
+
 # ---------------------------------------------------------------------------
 # layout / pack / unpack
 # ---------------------------------------------------------------------------
@@ -150,8 +157,11 @@ def test_packed_round_parity(name, avg_opt, key):
 
 
 @pytest.mark.parametrize("name", ["sgd", "momentum", "adamw"])
-def test_packed_round_parity_pallas_kernels(name, key):
-    """Same parity through the fused Pallas kernels (interpret on CPU)."""
+def test_packed_round_parity_pallas_kernels(name, key, monkeypatch):
+    """Same parity through the fused Pallas kernels (interpret on CPU),
+    which run on the flat path (a round that carries its leaves updates
+    them with the jnp formula)."""
+    _flat_only(monkeypatch)
     params, batch = make_problem(key)
     G = 2
     layout = packing.layout_of(params)
@@ -219,11 +229,15 @@ def test_packed_t_i_parity(key):
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
-def test_packed_t_i_adamw_parity(impl, key):
+def test_packed_t_i_adamw_parity(impl, key, monkeypatch):
     """The PR-1 leftover, lifted (DESIGN.md §10): per-node t_i with a
     count-dependent update runs the fused step vmapped over G with a
     PER-GROUP count vector. Multi-round parity vs the pytree path for
-    params, moments, AND the per-group counters (count_g = r * t_i[g])."""
+    params, moments, AND the per-group counters (count_g = r * t_i[g]).
+    With "pallas" the round is held on the flat path, where the fused
+    kernel runs (T=8 would carry the leaves)."""
+    if impl == "pallas":
+        _flat_only(monkeypatch)
     params, batch = make_problem(key)
     G = 3
     layout = packing.layout_of(params)
@@ -356,6 +370,163 @@ def test_packed_survives_schedule_and_clip_wrappers(key):
     for a, b in zip(jax.tree.leaves(lsgd.server_params(st)),
                     jax.tree.leaves(lsgd.server_params(sp, layout=layout))):
         np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# where the packed round crosses between the buffers and the leaves
+# ---------------------------------------------------------------------------
+
+
+# (optimizer, T, t_i, inner_mode, metrics); the rule picks leaves in all
+LEAF_CASES = [
+    ("sgd", 4, None, "fixed_batch", "final"),
+    ("momentum", 4, None, "fixed_batch", "final"),
+    ("momentum", 4, None, "fixed_batch", "traj"),
+    ("adamw", 3, None, "fixed_batch", "final"),
+    ("adamw", 3, None, "fixed_batch", "traj"),
+    ("momentum", 4, (1, 3, 4), "fixed_batch", "final"),  # shared count
+    ("adamw", 4, (1, 3, 4), "fixed_batch", "traj"),      # per-group count
+    ("momentum", 2, None, "microbatch", "final"),
+    ("adamw", 3, None, "microbatch", "traj"),
+]
+
+
+@pytest.mark.parametrize("name,T,t_i,mode,metrics", LEAF_CASES)
+def test_leaf_carry_round_parity(name, T, t_i, mode, metrics, key,
+                                 monkeypatch):
+    """The leaf-carrying round == the per-step flat round (the same
+    elementwise math, fused per leaf instead of per buffer, so to 1e-6;
+    counts and step counts exactly) == the pytree round, over two
+    rounds."""
+    params, batch = make_problem(key)
+    G = 3
+    if mode == "microbatch":
+        batch = jax.tree.map(lambda x: jnp.stack([x * (1 + 0.1 * t)
+                                                  for t in range(T)], 1),
+                             batch)
+    layout = packing.layout_of(params)
+    opt_t = optim.get(name, 0.05)
+    opt_p = optim.get(name, 0.05, packed=True, impl="jnp")
+    cfg = lsgd.LocalSGDConfig(n_groups=G, inner_steps=T, t_i=t_i,
+                              inner_mode=mode, metrics=metrics)
+    leaf = lsgd.make_local_round(quad_loss, opt_p, cfg, layout=layout)
+    assert leaf.buffer_path == "leaves"
+    assert leaf.buffer_passes == 2 * (1 + len(MOMENT_KEYS[name]))
+    _flat_only(monkeypatch)
+    flat = lsgd.make_local_round(quad_loss, opt_p, cfg, layout=layout)
+    assert flat.buffer_path == "flat"
+    assert flat.buffer_passes == 2 * T + (metrics == "final")
+    rnd_l, rnd_f = jax.jit(leaf), jax.jit(flat)
+    rnd_t = jax.jit(lsgd.make_local_round(quad_loss, opt_t, cfg))
+    sl = lsgd.init_state(params, opt_p, n_groups=G, layout=layout)
+    sf = jax.tree.map(jnp.copy, sl)
+    st = lsgd.init_state(params, opt_t, n_groups=G)
+    for _ in range(2):
+        sl, ml = rnd_l(sl, batch)
+        sf, mf = rnd_f(sf, batch)
+        st, mt = rnd_t(st, batch)
+    np.testing.assert_array_equal(sl["opt"]["count"], sf["opt"]["count"])
+    for a, b in zip(jax.tree.leaves(sf), jax.tree.leaves(sl)):
+        np.testing.assert_allclose(b, a, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(ml["inner_steps"], mf["inner_steps"])
+    for k in ("loss", "grad_sq", "grad_sq_first", "grad_sq_traj"):
+        if k in mf:
+            np.testing.assert_allclose(ml[k], mf[k], rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(lsgd.server_params(st)),
+                    jax.tree.leaves(lsgd.server_params(sl, layout=layout))):
+        np.testing.assert_allclose(b, a, rtol=1e-5, atol=1e-6)
+    for mk in MOMENT_KEYS[name]:
+        for g in range(G):
+            ref = packing.pack(
+                jax.tree.map(lambda x: x[g], st["opt"][mk]), layout)
+            np.testing.assert_allclose(sl["opt"][mk][g], ref,
+                                       rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("name,T,want", [
+    ("momentum", 4, "leaves"), ("sgd", 1, "leaves"), ("adamw", 3, "leaves"),
+    ("momentum", 1, "flat"), ("adamw", 2, "flat"),
+    ("momentum", 4, "shardexec"),
+])
+def test_carries_leaves_rule(name, T, want, key):
+    """S <= T carries the leaves; fewer local steps than streams, or a
+    round on the sharded execution layer, keeps the flat buffers."""
+    from jax.sharding import Mesh
+    from repro.sharding.shardexec import ShardExec
+
+    params, _ = make_problem(key)
+    opt = optim.get(name, 0.05, packed=True, impl="jnp")
+    S = packing.stream_layout_for(opt, packing.layout_of(params)).n_streams
+    sexec = None
+    layout = packing.layout_of(params)
+    if want == "shardexec":
+        mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                    ("data", "model"))
+        sexec = ShardExec(mesh=mesh, group_axes=("data",),
+                          shard_axes=("model",))
+        layout = packing.shard_layout(layout, sexec.n_shards)
+    assert lsgd.carries_leaves(S, T, sexec) == (want == "leaves")
+    cfg = lsgd.LocalSGDConfig(n_groups=1, inner_steps=T)
+    rnd = lsgd.make_local_round(quad_loss, opt, cfg, layout=layout,
+                                shardexec=sexec)
+    path = "leaves" if want == "leaves" else "flat"
+    assert rnd.buffer_path == path
+    assert rnd.buffer_passes == (2 * S if path == "leaves" else 2 * T + 1)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, its sub-jaxprs' included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("T,path", [(4, "leaves"), (1, "flat")])
+def test_scan_body_crosses_no_buffer(T, path, key):
+    """The leaf-carrying local steps hold no concatenate to, and no slice
+    of, a (G, N) buffer; the flat ones (the control) hold both."""
+    params, batch = make_problem(key)
+    G = 3
+    layout = packing.layout_of(params)
+    opt = optim.get("momentum", 0.05, packed=True, impl="jnp")
+    cfg = lsgd.LocalSGDConfig(n_groups=G, inner_steps=T)
+    rnd = lsgd.make_local_round(quad_loss, opt, cfg, layout=layout)
+    assert rnd.buffer_path == path
+    state = lsgd.init_state(params, opt, n_groups=G, layout=layout)
+    closed = jax.make_jaxpr(rnd)(state, batch)
+    scan, = [e for e in _eqns(closed.jaxpr) if e.primitive.name == "scan"]
+    body = list(_eqns(scan.params["jaxpr"].jaxpr))
+
+    def touches_buffer(e):
+        avals = ([v.aval for v in e.outvars] if e.primitive.name
+                 == "concatenate" else [v.aval for v in e.invars])
+        return any(getattr(a, "shape", ())[-1:] == (layout.padded,)
+                   for a in avals)
+
+    crossings = [e for e in body if e.primitive.name in ("concatenate",
+                                                         "slice")
+                 and touches_buffer(e)]
+    assert bool(crossings) == (path == "flat")
+
+
+@pytest.mark.parametrize("t_inner,path,passes", [
+    ("2", "leaves", 4), ("1", "flat", 3)])
+def test_buffer_passes_in_trace_meta(t_inner, path, passes, tmp_path,
+                                     monkeypatch):
+    from repro import obs
+    from repro.launch import compile_cache, train
+    from repro.obs import report
+
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
+    out = tmp_path / "trace.jsonl"
+    train.main(["--arch", "paper-mlp", "--reduced", "--packed", "--groups",
+                "2", "--per-group", "2", "--seq", "16", "--rounds", "1",
+                "--opt", "momentum", "--t-inner", t_inner,
+                "--trace", str(out)])
+    meta, _ = report.load(out)
+    assert meta["schema"] == obs.SCHEMA_VERSION
+    assert (meta["buffer_path"], meta["buffer_passes"]) == (path, passes)
 
 
 # ---------------------------------------------------------------------------

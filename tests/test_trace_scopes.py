@@ -38,6 +38,9 @@ ROUND_ROUNDS, ROUND_T = 2, 2
 
 ROUND_SCOPES = ("local_steps", "unpack", "fwd_bwd", "grad_pack", "opt_update",
                 "final_eval", "exchange", "round_metrics")
+# a round that carries its leaves crosses once a round, outside its steps
+LEAF_ROUND_SCOPES = ("state_unpack", "local_steps", "fwd_bwd", "opt_update",
+                     "final_eval", "state_pack", "exchange", "round_metrics")
 READERS = ("fwd_bwd_ms.train", "pack_ms.train", "opt_update_ms.train",
            "final_eval_ms.train", "exchange_ms.train",
            "round_metrics_ms.train")
@@ -49,7 +52,7 @@ PER_STEP = ("fwd_bwd_ms.train", "pack_ms.train", "opt_update_ms.train")
 # ---------------------------------------------------------------------------
 
 
-def _tiny_round_hlo(impl: str) -> str:
+def _tiny_round_hlo(impl: str, T: int) -> str:
     cfg = ArchConfig(name="tiny", source="test", family="dense", n_layers=2,
                      d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
                      d_ff=128, vocab_size=256, mlp_type="swiglu",
@@ -58,7 +61,7 @@ def _tiny_round_hlo(impl: str) -> str:
     model = build_model(cfg, schedule="rect")
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     layout = packing.layout_of(params)
-    G, T = 2, 2
+    G = 2
     opt = optim.get("momentum", 0.05, packed=True, beta=0.9, impl=impl)
     exch = comm.get_exchange("server", "fp32", G)
     lcfg = lsgd.LocalSGDConfig(n_groups=G, inner_steps=T, metrics="final")
@@ -70,10 +73,14 @@ def _tiny_round_hlo(impl: str) -> str:
     return jax.jit(rnd).lower(state, batch).compile().as_text()
 
 
+def _scope_paths(hlo: str) -> set:
+    return {ts.scope_path(n) for n in re.findall(r'op_name="([^"]*)"', hlo)}
+
+
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
 def test_packed_round_hlo_carries_every_scope(impl):
-    names = set(re.findall(r'op_name="([^"]*)"', _tiny_round_hlo(impl)))
-    paths = {ts.scope_path(n) for n in names}
+    # momentum has two streams: T=1 keeps the flat buffers, T=2 the leaves
+    paths = _scope_paths(_tiny_round_hlo(impl, 1))
     for scope in ROUND_SCOPES:
         assert any(scope in p.split("/") for p in paths), scope
     # the per-step scopes sit inside the local steps
@@ -83,6 +90,13 @@ def test_packed_round_hlo_carries_every_scope(impl):
         # the fused kernel runs under its own name, inside the update
         assert any(ts.holds(p, ("local_steps", "opt_update",
                                  "fused_momentum")) for p in paths)
+    paths = _scope_paths(_tiny_round_hlo(impl, 2))
+    for scope in LEAF_ROUND_SCOPES:
+        assert any(scope in p.split("/") for p in paths), scope
+    for scope in ("fwd_bwd", "opt_update"):
+        assert any(ts.holds(p, ("local_steps", scope)) for p in paths)
+    for scope in ("unpack", "grad_pack", "state_unpack", "state_pack"):
+        assert not any(ts.holds(p, ("local_steps", scope)) for p in paths)
 
 
 # ---------------------------------------------------------------------------
