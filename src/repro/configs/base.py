@@ -69,7 +69,7 @@ class ArchConfig:
     # MoE -------------------------------------------------------------------
     n_experts: int = 0
     top_k: int = 0
-    moe_impl: str = "densemask"  # densemask (paper-era baseline) | dispatch
+    moe_impl: str = "grouped"  # grouped (no-drop) | densemask (oracle)
 
     # SSM / hybrid ----------------------------------------------------------
     ssm_state: int = 0

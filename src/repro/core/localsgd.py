@@ -336,10 +336,13 @@ def _obs_round_metrics(exch, comm_state: dict, streams, consensus_pre,
 def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
                      layout: Optional[packing.Layout] = None,
                      exchange: Optional["comm_mod.Exchange"] = None,
-                     shardexec=None):
+                     shardexec=None, loss_has_aux: bool = False):
     """Build ``round(state_G, batch_G) -> (state_G, metrics)``.
 
-    loss_fn(params, batch) -> scalar.
+    loss_fn(params, batch) -> scalar; with ``loss_has_aux``, -> (scalar,
+    stats), a dict of arrays (a model's ``loss_and_stats``: its
+    ``expert_load`` where it has experts) that the packed round's "final"
+    metrics report at the round's result.
     state_G: {"params","opt"} with leading G axis on every leaf, plus a
              "comm" entry when the exchange carries state
              (``init_state(..., exchange=...)``).
@@ -363,14 +366,18 @@ def make_local_round(loss_fn: Callable, opt: Optimizer, cfg: LocalSGDConfig,
     then be the matching ``packing.ShardedLayout`` (DESIGN.md §9).
     """
     exch = _resolve_exchange(exchange, cfg, layout)
+    if loss_has_aux:
+        eval_fn, loss_fn = loss_fn, (lambda p, b, f=loss_fn: f(p, b)[0])
+    else:
+        eval_fn = lambda p, b, f=loss_fn: (f(p, b), {})
     if layout is not None or getattr(opt, "packed", False):
         if layout is None or not getattr(opt, "packed", False):
             raise ValueError(
                 "packed rounds need BOTH a packing.Layout and a packed "
                 "optimizer (optim.packed / optim.get(..., packed=True))")
         return _with_wire_bytes(
-            _make_packed_local_round(loss_fn, opt, cfg, layout, exch,
-                                     shardexec), exch, cfg)
+            _make_packed_local_round(loss_fn, eval_fn, opt, cfg, layout,
+                                     exch, shardexec), exch, cfg)
     if shardexec is not None:
         raise ValueError(
             "shardexec shards the packed flat buffer — it has no meaning "
@@ -516,7 +523,8 @@ def _where_groups(keep, new, old):
                                a, b), new, old)
 
 
-def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
+def _make_packed_local_round(loss_fn: Callable, eval_fn: Callable,
+                             opt: Optimizer,
                              cfg: LocalSGDConfig, layout: packing.Layout,
                              exch: "comm_mod.Exchange", shardexec=None):
     """Flat-buffer local round (see DESIGN.md §6).
@@ -721,20 +729,21 @@ def _make_packed_local_round(loss_fn: Callable, opt: Optimizer,
             # traj metrics report the grad made at step T-1 instead).
             # Evaluated per leaf — the norm needs no packed gradient, so
             # skipping the pack saves two full passes over the model.
-            vg = jax.value_and_grad(loss_fn)
+            # The loss's stats at the result join the metrics.
+            vg = jax.value_and_grad(eval_fn, has_aux=True)
 
             def final_eval(x, b):
                 view = (packing.as_layout_dtypes(x, layout) if leaves
                         else packing.unpack_for_compute(x, layout))
-                loss, g_tree = vg(view, b)
-                return loss, grad_sq_norm(g_tree)
+                (loss, stats), g_tree = vg(view, b)
+                return loss, grad_sq_norm(g_tree), stats
 
             with jax.named_scope("final_eval"):
-                loss_G, gsq_G = jax.vmap(final_eval)(
+                loss_G, gsq_G, stats_G = jax.vmap(final_eval)(
                     state_G["params"], last_batch)
             metrics = {"loss": loss_G,
                        "inner_steps": n_steps,
-                       "grad_sq": gsq_G}
+                       "grad_sq": gsq_G, **stats_G}
         if leaves:
             with jax.named_scope("state_pack"):
                 state_G = {"params": packing.pack(state_G["params"], layout),

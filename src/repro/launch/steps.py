@@ -676,7 +676,7 @@ def build_prefill_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
                 is_leaf=lambda x: isinstance(x, P))
 
     def prefill(params, batch):
-        x, _ = model.forward(params, batch)
+        x = model.forward(params, batch)[0]
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         last = x[:, -1:]
         return jnp.einsum("bsd,dv->bsv", last,

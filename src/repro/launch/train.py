@@ -344,10 +344,11 @@ def main(argv=None, devices=None) -> dict:
             n_groups=G, inner_steps=t_inner, t_i=t_i,
             threshold=args.threshold, max_inner=500, metrics=metrics,
             average_opt_state=avg_opt)
-        rnd = jax.jit(lsgd.make_local_round(model.loss, opt, lcfg,
+        rnd = jax.jit(lsgd.make_local_round(model.loss_and_stats, opt, lcfg,
                                             layout=layout,
                                             exchange=exchange,
-                                            shardexec=sexec),
+                                            shardexec=sexec,
+                                            loss_has_aux=True),
                       donate_argnums=(0,))
         state = lsgd.init_state(params, opt, n_groups=G, layout=layout,
                                 exchange=exchange,
@@ -412,8 +413,9 @@ def main(argv=None, devices=None) -> dict:
                         n_groups=G, inner_steps=t_cur, max_inner=500,
                         metrics=metrics, average_opt_state=avg_opt)
                     rnd = jax.jit(lsgd.make_local_round(
-                        model.loss, opt, lcfg, layout=layout,
-                        exchange=exchange, shardexec=sexec),
+                        model.loss_and_stats, opt, lcfg, layout=layout,
+                        exchange=exchange, shardexec=sexec,
+                        loss_has_aux=True),
                         donate_argnums=(0,))
                 if compile_s is None:
                     # compile the first round ahead of time to time it and

@@ -69,10 +69,25 @@ def _embed_lookup(table, tokens, dtype, impl: str = "onehot"):
     return jnp.moveaxis(out, 0, 1).reshape(B, S, D)
 
 
-def _chunked_ce(x, w_head, labels, mask=None, chunk=CE_CHUNK):
+def mask_padding(logits, vocab_size: int):
+    """Logits over the padded table's rows with the rows from
+    ``vocab_size`` on set to the lowest float, so that no softmax,
+    logsumexp or argmax reads them; the logits as they are (no op) where
+    the table has no padding."""
+    V = logits.shape[-1]
+    if V == vocab_size:
+        return logits
+    return jnp.where(jnp.arange(V) < vocab_size, logits,
+                     jnp.finfo(logits.dtype).min)
+
+
+def _chunked_ce(x, w_head, labels, mask=None, chunk=CE_CHUNK,
+                vocab_size=None):
     """Mean next-token CE without materializing full logits.
 
-    x (B,S,D) fp-activations, w_head (D,V), labels (B,S) int32.
+    x (B,S,D) fp-activations, w_head (D,V), labels (B,S) int32. With
+    ``vocab_size`` below V the softmax is over the first ``vocab_size``
+    rows only (``mask_padding``).
     """
     B, S, D = x.shape
     if S % chunk:
@@ -89,6 +104,8 @@ def _chunked_ce(x, w_head, labels, mask=None, chunk=CE_CHUNK):
         xb, lb, mb = inp
         logits = jnp.einsum("bsd,dv->bsv", xb, w_head.astype(xb.dtype))
         logits = logits.astype(jnp.float32)
+        if vocab_size is not None:
+            logits = mask_padding(logits, vocab_size)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lb[..., None], axis=-1)[..., 0]
         nll = (logz - gold) * mb
@@ -97,6 +114,13 @@ def _chunked_ce(x, w_head, labels, mask=None, chunk=CE_CHUNK):
     (tot, cnt), _ = jax.lax.scan(step, (jnp.zeros(()), jnp.zeros(())),
                                  (xc, lc, mc))
     return tot / jnp.maximum(cnt, 1.0)
+
+
+def _head_logits(x, head, cfg):
+    """(B,S,D) -> float32 logits (B,S,padded V) over the published
+    vocabulary (``mask_padding``)."""
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+    return mask_padding(logits.astype(jnp.float32), cfg.vocab_size)
 
 
 # ---------------------------------------------------------------------------
@@ -116,15 +140,19 @@ def _dense_block_defs(cfg):
 
 
 def _dense_block(p, x, cfg, schedule, block):
+    """One decoder layer: (new x, the layer's stats). The stats hold
+    ``aux`` (the router's load-balance loss, 0 without experts) and, with
+    experts, ``expert_load`` (``moe.expert_load``)."""
     h = attn.attention_forward(p["attn"], rms_norm(x, p["norm1"], cfg.norm_eps),
                                cfg, schedule=schedule, block=block)
     x = x + h
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if cfg.is_moe:
-        y, aux = moem.moe_forward(p["moe"], h2, cfg)
-    else:
-        y, aux = mlpm.mlp_forward(p["mlp"], h2, cfg), jnp.zeros(())
-    return x + y, aux
+        with jax.named_scope("moe"):
+            y, aux, load = moem.moe_forward(p["moe"], h2, cfg)
+        return x + y, {"aux": aux, "expert_load": load}
+    y = mlpm.mlp_forward(p["mlp"], h2, cfg)
+    return x + y, {"aux": jnp.zeros(())}
 
 
 def _dense_block_decode(p, x, cfg, cache, pos):
@@ -160,7 +188,9 @@ def _mamba_block_decode(p, x, cfg, cache):
 class Model:
     cfg: ArchConfig
     defs: Any                                   # ParamDef tree
-    forward: Callable                           # (params, batch) -> (x, aux)
+    # (params, batch) -> (x, aux, stats): stats are per-layer readings,
+    # ``{"expert_load": (n_layers,)}`` for a model with experts, else {}
+    forward: Callable
     decode_fn: Callable                         # (params, cache, tok, pos, extras)
 
     # -- params -------------------------------------------------------------
@@ -172,20 +202,24 @@ class Model:
 
     # -- training -----------------------------------------------------------
     def loss(self, params, batch):
-        x, aux = self.forward(params, batch)
+        return self.loss_and_stats(params, batch)[0]
+
+    def loss_and_stats(self, params, batch):
+        """(next-token CE over the published vocabulary + 0.01 * the
+        routers' load-balance loss, the forward's stats)."""
+        x, aux, stats = self.forward(params, batch)
         labels = batch["tokens"]
         lab = jnp.concatenate([labels[:, 1:],
                                jnp.zeros_like(labels[:, :1])], axis=1)
         mask = jnp.ones_like(lab, jnp.float32).at[:, -1].set(0.0)
         head = self._head(params)
-        ce = _chunked_ce(x, head, lab, mask)
-        return ce + 0.01 * aux
+        ce = _chunked_ce(x, head, lab, mask, vocab_size=self.cfg.vocab_size)
+        return ce + 0.01 * aux, stats
 
     def logits(self, params, batch):
         """Full logits — for small/smoke configs only."""
-        x, _ = self.forward(params, batch)
-        return jnp.einsum("bsd,dv->bsv", x,
-                          self._head(params).astype(x.dtype)).astype(jnp.float32)
+        x = self.forward(params, batch)[0]
+        return _head_logits(x, self._head(params), self.cfg)
 
     def _head(self, params):
         if self.cfg.tie_embeddings:
@@ -315,14 +349,15 @@ def _build_decoder(cfg, schedule, attn_block, layer_param_hook=None,
                 p = layer_param_hook(p)
             if layer_act_hook is not None:
                 x = layer_act_hook(x)
-            x, aux = _dense_block(p, x, cfg, schedule, attn_block)
+            x, st = _dense_block(p, x, cfg, schedule, attn_block)
             if layer_act_hook is not None:
                 x = layer_act_hook(x)
-            return x, aux
+            return x, st
 
-        x, auxs = jax.lax.scan(layer, x, params["blocks"])
+        x, st = jax.lax.scan(layer, x, params["blocks"])
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, jnp.sum(auxs)
+        aux = jnp.sum(st.pop("aux"))
+        return x, aux, st
 
     def decode(params, cache, tokens, pos, extras):
         dtype = jnp.dtype(cfg.dtype)
@@ -336,8 +371,7 @@ def _build_decoder(cfg, schedule, attn_block, layer_param_hook=None,
         x, new_kv = jax.lax.scan(layer, x, (params["blocks"], cache["kv"]))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-        return logits.astype(jnp.float32), {"kv": new_kv}
+        return _head_logits(x, head, cfg), {"kv": new_kv}
 
     def prefill(params, batch, cache_len):
         """Batched prefill: ONE forward populates the KV cache for every
@@ -357,7 +391,7 @@ def _build_decoder(cfg, schedule, attn_block, layer_param_hook=None,
             x = x + h
             h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
             if cfg.is_moe:
-                y, _ = moem.moe_forward(p["moe"], h2, cfg)
+                y, _, _ = moem.moe_forward(p["moe"], h2, cfg)
             else:
                 y = mlpm.mlp_forward(p["mlp"], h2, cfg)
             return x + y, (k, v)
@@ -365,8 +399,7 @@ def _build_decoder(cfg, schedule, attn_block, layer_param_hook=None,
         x, (ks, vs) = jax.lax.scan(layer, x, params["blocks"])
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x[:, -1:],
-                            head.astype(x.dtype)).astype(jnp.float32)
+        logits = _head_logits(x[:, -1:], head, cfg)
         pad = cache_len - S
         kc = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
         vc = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
@@ -420,7 +453,7 @@ def _build_hybrid(cfg, schedule, attn_block):
         idxs = jnp.arange(cfg.n_layers)
         x, _ = jax.lax.scan(layer, x, (params["blocks"], idxs))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, jnp.zeros(())
+        return x, jnp.zeros(()), {}
 
     def decode(params, cache, tokens, pos, extras):
         dtype = jnp.dtype(cfg.dtype)
@@ -455,8 +488,7 @@ def _build_hybrid(cfg, schedule, attn_block):
             layer, (x, cache["kv"]), (params["blocks"], cache["mamba"], idxs))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-        return logits.astype(jnp.float32), {"mamba": new_mamba, "kv": new_kvs}
+        return _head_logits(x, head, cfg), {"mamba": new_mamba, "kv": new_kvs}
 
     return Model(cfg, defs, forward, decode)
 
@@ -494,7 +526,7 @@ def _build_xlstm(cfg):
         x, _ = jax.lax.scan(group, x, {"m": params["mlstm"],
                                        "s": params["slstm"]})
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, jnp.zeros(())
+        return x, jnp.zeros(()), {}
 
     def decode(params, cache, tokens, pos, extras):
         dtype = jnp.dtype(cfg.dtype)
@@ -519,8 +551,7 @@ def _build_xlstm(cfg):
                        cache["mlstm"], cache["slstm"]))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-        return logits.astype(jnp.float32), {"mlstm": new_m, "slstm": new_s}
+        return _head_logits(x, head, cfg), {"mlstm": new_m, "slstm": new_s}
 
     return Model(cfg, defs, forward, decode)
 
@@ -554,12 +585,12 @@ def _build_vlm(cfg, schedule, attn_block):
 
         @jax.checkpoint
         def layer(x, p):
-            x, aux = _dense_block(p, x, cfg, schedule, attn_block)
-            return x, aux
+            x, st = _dense_block(p, x, cfg, schedule, attn_block)
+            return x, st["aux"]
 
         x, auxs = jax.lax.scan(layer, x, params["blocks"])
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, jnp.sum(auxs)
+        return x, jnp.sum(auxs), {}
 
     return Model(cfg, defs, forward, base.decode_fn)
 
@@ -633,7 +664,7 @@ def _build_whisper(cfg, schedule, attn_block):
 
         x, _ = jax.lax.scan(layer, x, params["dec"])
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x, jnp.zeros(())
+        return x, jnp.zeros(()), {}
 
     def decode(params, cache, tokens, pos, extras):
         dtype = jnp.dtype(cfg.dtype)
@@ -658,8 +689,7 @@ def _build_whisper(cfg, schedule, attn_block):
                        cache["cross_k"], cache["cross_v"]))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-        return logits.astype(jnp.float32), {
+        return _head_logits(x, head, cfg), {
             "kv": new_kv, "cross_k": cache["cross_k"],
             "cross_v": cache["cross_v"]}
 

@@ -153,7 +153,12 @@ def blocked_causal_attention(q, k, v, block: int, schedule: str = "tri"):
                          jnp.where(j == i, tri_mask, jnp.zeros_like(tri_mask)))
         s = jnp.where(keep[None, None, None], s, NEG_INF)
         mi, li, ai = m[i], l[i], acc[i]
-        m_new = jnp.maximum(mi, jnp.max(s, axis=-1))
+        # The running max only keeps exp() in range; the result does not
+        # depend on it, so it takes no gradient (as in jax.nn.softmax).
+        # Differentiating it would divide by the count of scores equal to
+        # their max, which is 0 where the remat's recomputed max and
+        # scores round differently (bf16 scores on the TPU): NaN.
+        m_new = jax.lax.stop_gradient(jnp.maximum(mi, jnp.max(s, axis=-1)))
         corr = jnp.exp(mi - m_new)
         pblk = jnp.exp(s - m_new[..., None])
         l_new = li * corr + jnp.sum(pblk, axis=-1)

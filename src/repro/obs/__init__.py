@@ -27,6 +27,8 @@ SCHEMA_VERSION = 1
 # configuration (the uniform contract, DESIGN.md §13). Per-stream keys
 # ride alongside: wire_bytes/<stream> and codec_err/<stream> for every
 # stream the round exchanges (params + averaged moment buffers).
+# A packed round built with ``loss_has_aux`` adds the loss's stats:
+# ``expert_load`` (G, n_layers) for a model with experts.
 ROUND_KEYS = (
     "loss", "grad_sq", "inner_steps",
     "wire_bytes", "wire_bytes_up", "wire_bytes_down",

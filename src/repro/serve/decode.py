@@ -272,7 +272,7 @@ def _build_decoder_programs(model, geom, attn_fn):
             x = x + h
             h2 = rms_norm(x, p["norm2"], eps)
             if cfg.is_moe:
-                y, _ = moem.moe_forward(p["moe"], h2, cfg)
+                y, _, _ = moem.moe_forward(p["moe"], h2, cfg)
             else:
                 y = mlpm.mlp_forward(p["mlp"], h2, cfg)
             pool = write_prefill_kv(pool, rk[:nblk_p],

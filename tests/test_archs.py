@@ -33,7 +33,7 @@ def arch_setup(request):
 def test_forward_shapes(arch_setup, key):
     cfg, model, params = arch_setup
     batch = make_batch(cfg, key)
-    x, aux = model.forward(params, batch)
+    x, aux, _ = model.forward(params, batch)
     assert x.shape == (2, 32, cfg.d_model)
     assert bool(jnp.all(jnp.isfinite(x.astype(jnp.float32))))
     assert bool(jnp.isfinite(aux))

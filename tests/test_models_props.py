@@ -32,6 +32,26 @@ def test_blocked_equals_full(schedule, B, S, H, KV, hd, block, key):
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
 
 
+@pytest.mark.parametrize("schedule", ["tri", "rect"])
+def test_blocked_grads_equal_full(schedule, key):
+    """The running max takes no gradient: q, k and v get full attention's."""
+    B, S, H, KV, hd, block = 2, 16, 4, 2, 8, 4
+    ks = jax.random.split(key, 4)
+    q = jax.random.normal(ks[0], (B, S, H, hd))
+    k = jax.random.normal(ks[1], (B, S, KV, hd))
+    v = jax.random.normal(ks[2], (B, S, KV, hd))
+    w = jax.random.normal(ks[3], (B, S, H, hd))
+    mask = jnp.tril(jnp.ones((S, S), bool))
+
+    def f(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2))
+    want = f(lambda q, k, v: attn.full_attention(q, k, v, mask))(q, k, v)
+    got = f(lambda q, k, v: attn.blocked_causal_attention(
+        q, k, v, block, schedule))(q, k, v)
+    for g, h in zip(got, want):
+        np.testing.assert_allclose(g, h, atol=1e-5, rtol=1e-4)
+
+
 def test_blocked_causality(key):
     B, S, H, hd, block = 1, 16, 2, 8, 4
     ks = jax.random.split(key, 3)
@@ -114,22 +134,23 @@ def test_rms_norm_unit_variance(key):
 
 
 # ---------------------------------------------------------------------------
-# MoE: dispatch == densemask when capacity is unbounded
+# MoE: the grouped (no-drop) layer == densemask
 # ---------------------------------------------------------------------------
 
 
 def test_moe_dispatch_matches_densemask(key):
+    """The grouped layer, which dispatches every routed pair to its expert
+    (no capacity, nothing dropped), equals the dense-masked oracle."""
     cfg = get_config("granite-moe-1b-a400m").reduced()
     defs = moem.moe_defs(cfg)
     from repro.models.layers import init_params
     p = init_params(defs, key)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, cfg.d_model))
-    y_dense, aux_d = moem.moe_densemask(p, x, cfg)
-    # capacity_factor big enough that no token is dropped
-    y_disp, aux_s = moem.moe_dispatch(p, x, cfg,
-                                      capacity_factor=float(cfg.n_experts))
-    np.testing.assert_allclose(y_disp, y_dense, atol=1e-4, rtol=1e-4)
-    np.testing.assert_allclose(aux_d, aux_s, rtol=1e-5)
+    y_dense, aux_d, load_d = moem.moe_densemask(p, x, cfg)
+    y_grp, aux_g, load_g = moem.moe_grouped(p, x, cfg)
+    np.testing.assert_allclose(y_grp, y_dense, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(aux_d, aux_g, rtol=1e-5)
+    np.testing.assert_allclose(load_d, load_g)
 
 
 def test_moe_decode_matches_forward(key):
@@ -139,7 +160,7 @@ def test_moe_decode_matches_forward(key):
     from repro.models.layers import init_params
     p = init_params(defs, key)
     x = jax.random.normal(jax.random.PRNGKey(3), (3, 1, cfg.d_model))
-    y_fwd, _ = moem.moe_densemask(p, x, cfg)
+    y_fwd, _, _ = moem.moe_densemask(p, x, cfg)
     y_dec, _ = moem.moe_decode(p, x, cfg)
     np.testing.assert_allclose(y_dec, y_fwd, atol=1e-4, rtol=1e-4)
 

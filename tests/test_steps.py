@@ -129,9 +129,9 @@ def test_moe_impl_override(key):
     cfg = get_config("granite-moe-1b-a400m").reduced()
     mesh = make_local_mesh(1, 1)
     built = build_step(cfg, SMALL_TRAIN, mesh, t_inner=1,
-                       moe_impl="dispatch")
+                       moe_impl="densemask")
     model = build_model(
-        dataclasses.replace(cfg, moe_impl="dispatch"), schedule="rect")
+        dataclasses.replace(cfg, moe_impl="densemask"), schedule="rect")
     params = model.init(key)
     G = built.meta["groups"]
     state = lsgd.init_state(params, optim.sgd(1e-3), n_groups=G)

@@ -142,3 +142,30 @@ def test_paged_decode_attention_compiles(one_chip, arch, page_size):
         ((geom.n_pages, geom.page_elems), jnp.float32),
         ((slots, nblk), i32), ((slots, nblk), i32), ((slots,), i32))
     _assert_kernel(hlo)
+
+
+def test_grouped_expert_products_compile(one_chip, monkeypatch):
+    """The expert layer's megablox ``gmm``/``tgmm`` at granite-train-g2t4's
+    shapes: two groups vmapped (their grouped products folded into one
+    over 2 x 32 experts), 2,048 tokens a group (2 x 1,024) at top-8, d 1024,
+    f 512; the three products forward and backward."""
+    import repro.kernels
+    from repro.models import moe
+
+    monkeypatch.setattr(repro.kernels, "use_interpret", lambda: False)
+    monkeypatch.setattr(moe, "use_interpret", lambda: False)
+    Gr, E, D, F, M = 2, 32, 1024, 512, 2 * 1024 * 8
+
+    def experts(wg, wu, wd, rows, sizes):
+        def one(wg, wu, wd, rows, sizes):
+            mm = lambda r, w: moe.grouped_matmul(r, w.astype(r.dtype), sizes)
+            y = mm(jax.nn.silu(mm(rows, wg)) * mm(rows, wu), wd)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return jax.vmap(jax.grad(one, argnums=(0, 1, 2, 3)))(
+            wg, wu, wd, rows, sizes)
+
+    w_in, w_out = ((Gr, E, D, F), jnp.float32), ((Gr, E, F, D), jnp.float32)
+    hlo = _compile(experts, one_chip, w_in, w_in, w_out,
+                   ((Gr, M, D), jnp.bfloat16), ((Gr, E), jnp.int32))
+    _assert_kernel(hlo)
+    assert "tgmm" in hlo and "gmm" in hlo
